@@ -25,6 +25,8 @@ BENCHES = ["table2", "fig4a", "fig4b", "fig4b_micro", "fig4c", "fig5",
 
 
 def main() -> None:
+    from repro.common.device import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of " + ",".join(BENCHES))

@@ -45,6 +45,7 @@ import time
 import numpy as np
 
 from benchmarks.common import row
+from repro.common.device import use_compile_cache
 from repro.distributed.worker import PhaseFailureInjector
 from repro.service.client import ALClient, serve_tcp
 from repro.service.config import ALServiceConfig
@@ -453,6 +454,7 @@ def run(loads=(10.0, 30.0, 60.0), n_ops=150, tenants=3, seed=0):
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default=None, metavar="PATH")
     ap.add_argument("--loads", default=None,

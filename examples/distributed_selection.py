@@ -24,7 +24,7 @@ import numpy as np  # noqa: E402
 
 from repro.core.selection import (distributed_k_center,  # noqa: E402
                                   distributed_top_k, sharded_scores)
-from repro.launch.mesh import make_debug_mesh, set_mesh  # noqa: E402
+from repro.launch.mesh import make_debug_mesh  # noqa: E402
 
 
 def main():
@@ -36,7 +36,7 @@ def main():
     logits = jnp.asarray(rng.normal(size=(N, C)) * 2, jnp.float32)
     emb = jnp.asarray(rng.normal(size=(N, D)), jnp.float32)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         t0 = time.perf_counter()
         scores = sharded_scores(logits, "lc", mesh)        # stays sharded
         idx_u = distributed_top_k(scores, BUDGET, mesh)    # replicated result

@@ -23,7 +23,6 @@ active_learning:
   model:
     name: "synthetic_cnn"
     batch_size: 16
-  device: CPU
 al_worker:
   protocol: "tcp"
   host: "127.0.0.1"

@@ -6,39 +6,28 @@ Score conventions follow Settles' survey [46] / the paper's references:
   RC  ratio confidence      p(2) / p(1)               (ratio near 1 = pick)
   ES  entropy sampling      -sum p log p
 
-``*_scores_from_logits`` are the fused paths the Pallas kernel implements
+Every score runs through the fused Pallas kernel on TPU
 (repro/kernels/uncertainty): one streaming pass over the class/vocab axis,
-no materialized softmax — this is the serving hot-spot when the scorer is an
-LLM with a 100k-256k vocab.
+no materialized softmax — the serving hot-spot when the scorer is an LLM
+with a 100k-256k vocab. Served pools carry softmax probs, which the kernel
+scores as the logits ``log p``; ``scores_from_logits`` takes raw logits.
 """
 from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.strategies.base import Strategy, top_k_select
+from repro.kernels.uncertainty import ops as unc_ops
 
 
-def lc_scores(probs):
-    return 1.0 - jnp.max(probs, axis=-1)
-
-
-def mc_scores(probs):
-    top2 = jax.lax.top_k(probs, 2)[0]
-    return -(top2[..., 0] - top2[..., 1])
-
-
-def rc_scores(probs):
-    top2 = jax.lax.top_k(probs, 2)[0]
-    return top2[..., 1] / jnp.maximum(top2[..., 0], 1e-12)
-
-
-def es_scores(probs):
-    p = jnp.clip(probs, 1e-12, 1.0)
-    return -jnp.sum(p * jnp.log(p), axis=-1)
-
+# per-row scores of softmax probs: the fused Pallas kernel on TPU, the
+# closed forms (kernels/uncertainty/ref.py) elsewhere
+lc_scores = functools.partial(unc_ops.probs_scores, kind="lc")
+mc_scores = functools.partial(unc_ops.probs_scores, kind="mc")
+rc_scores = functools.partial(unc_ops.probs_scores, kind="rc")
+es_scores = functools.partial(unc_ops.probs_scores, kind="es")
 
 SCORE_FNS = {"lc": lc_scores, "mc": mc_scores, "rc": rc_scores,
              "es": es_scores}
@@ -46,8 +35,7 @@ SCORE_FNS = {"lc": lc_scores, "mc": mc_scores, "rc": rc_scores,
 
 def scores_from_logits(logits, kind: str, impl: str = "auto"):
     """Fused logits->score (kernel or reference; see kernels/uncertainty)."""
-    from repro.kernels.uncertainty import ops
-    return ops.uncertainty_scores(logits, kind, impl=impl)
+    return unc_ops.uncertainty_scores(logits, kind, impl=impl)
 
 
 def _make(kind: str) -> Strategy:

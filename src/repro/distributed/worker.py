@@ -8,20 +8,18 @@ already fans out on, so the existing local-propose / global-dedup merge is
 the cross-worker protocol unchanged — but each map now runs under
 supervision:
 
-  * one LANE per shard — a dedicated single-thread executor (``thread``
-    backend, the default) optionally paired with a real OS process
-    (``process`` backend) that executes registered picklable jobs such as
-    the canonical embed batch;
+  * one LANE per shard — a dedicated single-thread executor in the
+    serving process (a chip belongs to one process, so lanes are threads
+    that share it, never child processes);
   * every task is timed and fed to a ``StragglerMonitor``
     (distributed.fault_tolerance) — straggler events surface in
     ``stats()``;
   * a ``PhaseFailureInjector`` can deterministically kill a worker at the
     Nth task of a named phase (``embed`` / ``propose`` / ``ingest``), and
-    ``kill()`` hard-kills a lane (SIGKILL for process lanes) for
-    non-deterministic tests;
-  * a dead worker — injected kill, hard kill, hung task past ``timeout_s``,
-    or a broken process pipe — is detected by the supervising caller, the
-    lane is RESTARTED (generation bump; fresh thread/process), the
+    ``kill()`` marks a lane dead for non-deterministic tests;
+  * a dead worker — injected kill, killed lane, or hung task past
+    ``timeout_s`` — is detected by the supervising caller, the lane is
+    RESTARTED (generation bump; fresh thread), the
     caller-supplied ``on_death(shard)`` recovery hook runs (the AL service
     resets the shard's artifact columns there, forcing a re-embed from raw
     + content keys), and the task retries with bounded backoff.  Selections
@@ -39,7 +37,6 @@ module.
 from __future__ import annotations
 
 import concurrent.futures as cf
-import multiprocessing as mp
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
@@ -49,14 +46,14 @@ from repro.distributed.fault_tolerance import (SimulatedFailure,
 
 
 class WorkerDeath(RuntimeError):
-    """A shard worker died (injected, killed, hung, or broken pipe)."""
+    """A shard worker died (injected, killed, or hung)."""
 
 
 class PhaseFailureInjector:
     """Deterministic worker-kill schedule keyed by PHASE of the shard path.
 
-    ``fail_at`` maps a phase name (``embed`` / ``propose`` / ``ingest`` /
-    ``job``) to the 0-based task indices *within that phase* at which the
+    ``fail_at`` maps a phase name (``embed`` / ``propose`` / ``ingest``)
+    to the 0-based task indices *within that phase* at which the
     worker executing the task dies (raises ``SimulatedFailure``, which the
     pool treats exactly like a hard kill: restart + recover + retry).
     Each scheduled index fires once, so the retried task survives —
@@ -81,105 +78,26 @@ class PhaseFailureInjector:
                     f"injected worker death at {phase}[{i}]")
 
 
-# --------------------------------------------------------------------------
-# Registered process jobs: the only work shipped across the process
-# boundary. Jobs are pure functions of their (picklable) payload plus a
-# per-process cache dict for expensive lazy state (e.g. the backend).
-# --------------------------------------------------------------------------
-_JOBS: Dict[str, Callable[[Any, dict], Any]] = {}
-
-
-def register_job(name: str):
-    def deco(fn):
-        _JOBS[name] = fn
-        return fn
-    return deco
-
-
-@register_job("echo")
-def _job_echo(payload, cache):
-    return payload
-
-
-@register_job("embed_batch")
-def _job_embed_batch(payload, cache):
-    """The canonical embed chunk (service layer's ``_feats_for`` contract):
-    preprocess the raw rows, zero-pad to the one canonical ``batch_size``
-    shape, run the feature forward, return the valid rows. Pure in
-    (config, raw bytes) — the worker process rebuilds the backend from the
-    config once and caches it, so the feature bytes match the in-process
-    path bit for bit (backend construction is deterministic from config).
-    """
-    import numpy as np
-
-    from repro.service.backends import make_backend
-    from repro.service.config import ALServiceConfig
-
-    cfg_d = payload["config"]
-    key = tuple(sorted(cfg_d.items()))
-    backend = cache.get(key)
-    if backend is None:
-        cfg = ALServiceConfig(**cfg_d)
-        backend = make_backend(cfg.model_name, config=cfg)
-        cache[key] = backend
-    raw = np.asarray(payload["raw"])
-    bs = max(int(payload["bs"]), 1)
-    x = np.asarray(backend.preprocess(raw))
-    n = x.shape[0]
-    if n < bs:
-        x = np.concatenate([x, np.zeros((bs - n,) + x.shape[1:], x.dtype)])
-    return np.asarray(backend.features(x))[:n]
-
-
-def _process_main(conn):
-    """Worker-process loop: execute registered jobs until EOF/None."""
-    cache: dict = {}
-    while True:
-        try:
-            msg = conn.recv()
-        except EOFError:
-            return
-        if msg is None:
-            return
-        name, payload = msg
-        try:
-            conn.send(("ok", _JOBS[name](payload, cache)))
-        except BaseException as e:  # ship the failure, keep serving
-            conn.send(("err", f"{type(e).__name__}: {e}"))
-
-
 class _Lane:
-    """One shard's worker lane: a dedicated single-thread executor, plus a
-    paired OS process under the ``process`` backend. ``generation`` bumps
-    on every restart."""
+    """One shard's worker lane: a dedicated single-thread executor.
+    ``generation`` bumps on every restart."""
 
-    def __init__(self, index: int, kind: str, device=None):
+    def __init__(self, index: int, device=None):
         self.index = index
-        self.kind = kind
         self.device = device
         self.generation = 0
         self.dead = False
-        self._proc = None
-        self._conn = None
         self._ex = cf.ThreadPoolExecutor(
             max_workers=1, thread_name_prefix=f"shard{index}-g0")
 
-    # -- liveness ---------------------------------------------------------
     def alive(self) -> bool:
-        if self.dead:
-            return False
-        if self._proc is not None and not self._proc.is_alive():
-            return False
-        return True
+        return not self.dead
 
     def kill(self) -> None:
-        """Hard-kill the lane: SIGKILL the paired process (if any) and mark
-        the lane dead so its next task raises ``WorkerDeath`` — thread
-        lanes cannot be preempted mid-task, so an in-flight task is caught
-        by the supervisor's timeout instead."""
+        """Mark the lane dead so its next task raises ``WorkerDeath`` —
+        thread lanes cannot be preempted mid-task, so an in-flight task is
+        caught by the supervisor's timeout instead."""
         self.dead = True
-        if self._proc is not None and self._proc.is_alive():
-            self._proc.kill()
 
     def restart(self) -> None:
         self.generation += 1
@@ -189,62 +107,12 @@ class _Lane:
             max_workers=1,
             thread_name_prefix=f"shard{self.index}-g{self.generation}")
         old.shutdown(wait=False)   # a hung task finishes into the void
-        self._stop_process()
 
-    # -- thread tasks -----------------------------------------------------
     def submit(self, fn, *args) -> cf.Future:
         return self._ex.submit(fn, *args)
 
-    # -- process jobs -----------------------------------------------------
-    def _ensure_process(self):
-        if self._proc is None or not self._proc.is_alive():
-            ctx = mp.get_context("spawn")
-            self._conn, child = ctx.Pipe()
-            self._proc = ctx.Process(target=_process_main, args=(child,),
-                                     daemon=True,
-                                     name=f"shard{self.index}-proc")
-            self._proc.start()
-            child.close()
-        return self._conn
-
-    def run_job(self, name: str, payload, timeout_s: float):
-        """One registered job on the paired process; raises ``WorkerDeath``
-        on a dead/hung process, ``RuntimeError`` on a job error."""
-        if self.dead:
-            raise WorkerDeath(f"lane {self.index} was killed")
-        try:
-            conn = self._ensure_process()
-            conn.send((name, payload))
-            if not conn.poll(timeout_s):
-                raise WorkerDeath(
-                    f"shard {self.index} job {name!r} hung past "
-                    f"{timeout_s}s")
-            status, value = conn.recv()
-        except (EOFError, BrokenPipeError, OSError) as e:
-            raise WorkerDeath(
-                f"shard {self.index} worker process died during "
-                f"{name!r}: {e!r}") from e
-        if status != "ok":
-            raise RuntimeError(f"job {name!r} failed on shard "
-                               f"{self.index}: {value}")
-        return value
-
-    def _stop_process(self):
-        if self._proc is not None:
-            if self._proc.is_alive():
-                try:
-                    self._conn.send(None)
-                except (BrokenPipeError, OSError):
-                    pass
-                self._proc.join(timeout=1.0)
-                if self._proc.is_alive():
-                    self._proc.kill()
-            self._proc = None
-            self._conn = None
-
     def shutdown(self):
         self._ex.shutdown(wait=False)
-        self._stop_process()
 
 
 def _lane_devices(n_lanes: int, devices=None) -> List[Any]:
@@ -272,24 +140,20 @@ class ShardWorkerPool:
     death before the retry — the service layer's shard-recovery hook.
     """
 
-    def __init__(self, n_shards: int, *, kind: str = "thread",
+    def __init__(self, n_shards: int, *,
                  timeout_s: float = 30.0, max_retries: int = 2,
                  backoff_s: float = 0.05,
                  injector: Optional[PhaseFailureInjector] = None,
                  monitor: Optional[StragglerMonitor] = None,
                  devices=None):
-        if kind not in ("thread", "process"):
-            raise ValueError(f"worker backend must be 'thread' or "
-                             f"'process', got {kind!r}")
         self.n_shards = max(int(n_shards), 1)
-        self.kind = kind
         self.timeout_s = float(timeout_s)
         self.max_retries = max(int(max_retries), 0)
         self.backoff_s = float(backoff_s)
         self.injector = injector
         self.monitor = monitor if monitor is not None else StragglerMonitor()
         self._devices = _lane_devices(self.n_shards, devices)
-        self._lanes = [_Lane(i, kind, self._devices[i])
+        self._lanes = [_Lane(i, self._devices[i])
                        for i in range(self.n_shards)]
         self._lock = threading.Lock()
         self.restarts = 0          # lane restarts after a worker death
@@ -369,46 +233,6 @@ class ShardWorkerPool:
             time.sleep(self.backoff_s * attempt)
             fut = lane.submit(self._wrap, phase, fn, item, lane)
 
-    # -- process jobs ------------------------------------------------------
-    def run_job(self, shard: int, name: str, payload,
-                on_death: Optional[Callable] = None):
-        """A registered job on the shard's paired worker process, under
-        the same supervision (injection, straggler timing, restart +
-        bounded retry). Only meaningful on the ``process`` backend —
-        thread pools run jobs inline for parity."""
-        shard = shard % self.n_shards
-        lane = self._lanes[shard]
-        attempt = 0
-        while True:
-            death = None
-            try:
-                if self.injector is not None:
-                    self.injector.maybe_fail("job")
-                t0 = time.perf_counter()
-                if self.kind == "process":
-                    out = lane.run_job(name, payload, self.timeout_s)
-                else:
-                    out = _JOBS[name](payload, {})
-                with self._lock:
-                    self.tasks += 1
-                    self.monitor.observe(self.tasks,
-                                         time.perf_counter() - t0)
-                return out
-            except (SimulatedFailure, WorkerDeath) as e:
-                death = e
-            with self._lock:
-                self.restarts += 1
-                self.deaths.append(f"job/{name}/shard{shard}: {death}")
-            lane.restart()
-            if on_death is not None:
-                on_death(shard)
-            attempt += 1
-            if attempt > self.max_retries:
-                raise WorkerDeath(
-                    f"shard {shard} job {name!r} failed after "
-                    f"{attempt} attempts: {death}") from death
-            time.sleep(self.backoff_s * attempt)
-
     # -- probes / chaos ----------------------------------------------------
     def kill(self, shard: int) -> None:
         self._lanes[shard % self.n_shards].kill()
@@ -420,7 +244,6 @@ class ShardWorkerPool:
     def stats(self) -> dict:
         with self._lock:
             return {
-                "backend": self.kind,
                 "lanes": self.n_shards,
                 "generations": [ln.generation for ln in self._lanes],
                 "alive": [ln.alive() for ln in self._lanes],

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 NEG = -1e30
 
 
@@ -97,7 +95,7 @@ def decode_attention_pallas(q, k_cache, v_cache, cur_len, *,
         functools.partial(_kernel, nk=nk, kb=kb, scale=scale, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KH, G, D), q.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(cur, qg, k_cache, v_cache)

@@ -2,10 +2,13 @@
 masking.
 
 Grid: (B, H, q_blocks, kv_blocks) — first three parallel, kv sequential.
-Online-softmax carry (m, l, acc) lives in VMEM scratch; K/V blocks are
-indexed at h // G so grouped query heads share one KV stream (GQA without
-materializing repeated KV). Block shapes default to (128, head_dim) tiles —
-MXU-aligned for head_dim in {64, 128, 256}.
+The wrapper moves heads ahead of the sequence, (B, S, H, D) -> (B, H, S, D),
+so every block is a (seq, head_dim) tile: the TPU tiling wants the last two
+block dims to be (multiple of 8, whole head_dim). Online-softmax carry (m,
+l, acc) lives in VMEM scratch as columns; K/V blocks are indexed at h // G
+so grouped query heads share one KV stream (GQA without materializing
+repeated KV). Block shapes default to (128, head_dim) tiles — MXU-aligned
+for head_dim in {64, 128, 256}.
 
 Serving-path kernel: forward only (training uses the chunked jnp attention,
 which XLA differentiates; see DESIGN.md §kernels).
@@ -19,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels import compat
 
 NEG = -1e30
 
@@ -36,9 +37,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)            # (qb, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)            # (kb, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32)                  # (qb, D)
+    k = k_ref[0, 0].astype(jnp.float32)                  # (kb, D)
+    v = v_ref[0, 0].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
@@ -53,18 +54,18 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
     s = jnp.where(mask, s, NEG)
 
     m_prev = m_s[...]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_cur[:, None])
-    corr = jnp.exp(m_prev - m_cur)
-    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=-1)
-    acc_s[...] = acc_s[...] * corr[:, None] + jax.lax.dot_general(
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_cur)
+    corr = jnp.exp(m_prev - m_cur)                       # (qb, 1)
+    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_s[...] = m_cur
 
     @pl.when(j == nk - 1)
     def _fin():
         l = jnp.maximum(l_s[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_s[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_s[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
@@ -81,33 +82,34 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
     kb = min(kv_block, Skv)
     nq = -(-Sq // qb)
     nk = -(-Skv // kb)
+    q, k, v = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))  # (B, H, S, D)
     if nq * qb != Sq:
-        q = jnp.pad(q, ((0, 0), (0, nq * qb - Sq), (0, 0), (0, 0)))
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, nq * qb - Sq), (0, 0)))
     if nk * kb != Skv:
-        k = jnp.pad(k, ((0, 0), (0, nk * kb - Skv), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, nk * kb - Skv), (0, 0), (0, 0)))
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, nk * kb - Skv), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, nk * kb - Skv), (0, 0)))
 
     out = pl.pallas_call(
         functools.partial(_kernel, nk=nk, qb=qb, kb=kb, skv=Skv, scale=scale,
                           causal=causal, window=window),
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, qb, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, kb, 1, D),
-                         lambda b, h, i, j, G=G: (b, j, h // G, 0)),
-            pl.BlockSpec((1, kb, 1, D),
-                         lambda b, h, i, j, G=G: (b, j, h // G, 0)),
+            pl.BlockSpec((1, 1, qb, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, kb, D),
+                         lambda b, h, i, j, G=G: (b, h // G, j, 0)),
+            pl.BlockSpec((1, 1, kb, D),
+                         lambda b, h, i, j, G=G: (b, h // G, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, qb, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, nq * qb, H, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, qb, D), lambda b, h, i, j: (b, h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, nq * qb, D), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((qb,), jnp.float32),
-            pltpu.VMEM((qb,), jnp.float32),
+            pltpu.VMEM((qb, 1), jnp.float32),
+            pltpu.VMEM((qb, 1), jnp.float32),
             pltpu.VMEM((qb, D), jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
     )(q, k, v)
-    return out[:, :Sq]
+    return jnp.swapaxes(out[:, :, :Sq], 1, 2)

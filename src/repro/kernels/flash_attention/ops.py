@@ -3,22 +3,14 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
-
+from repro.common.device import on_tpu
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 def flash_attention_auto(q, k, v, *, causal: bool = True,
                          window: Optional[int] = None,
                          scale: Optional[float] = None, **chunk_kw):
-    if _on_tpu():
+    if on_tpu():
         return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                       scale=scale)
     from repro.models.layers.attention import chunked_attention

@@ -17,19 +17,19 @@ against VMEM pressure:
     no longer fit the VMEM budget.
 
 The tuner sweeps both over the same op-accounted HBM model the benchmarks
-use (bytes actually moved per fused round), rejects candidates whose tiles
-exceed the VMEM budget (~16 MB/core on TPU; we keep half as headroom for
-double buffering), and — when a TPU is attached or ``measure=True`` —
-re-ranks the model's shortlist by measured wall clock. Winners are cached
-per (N, d, dtype) shape key; ``report()`` exposes the cache so benchmarks
-can print the chosen blocks.
+use (bytes actually moved per fused round), rejects candidates whose
+per-step VMEM model exceeds the budget, and — with ``measure=True`` —
+re-ranks the model's shortlist by measured wall clock.
+Every candidate is a multiple of 128 rows, the lane tiling of the kernels'
+(1, n_block) vector blocks. Winners are cached per (N, d, dtype) shape key;
+``report()`` exposes the cache so benchmarks can print the chosen blocks.
 
 Winners also persist to a result directory (``REPRO_AUTOTUNE_CACHE_DIR``,
-default ``~/.cache/repro/pairwise-autotune``; empty string disables) as
-one small JSON per shape key, so measured picks survive process restarts —
-and CI restores the directory across workflow runs with ``actions/cache``
-instead of re-measuring every run. Corrupt or unreadable entries are
-ignored and re-tuned.
+default ``<repo>/.autotune_cache``; empty string disables) as one small
+JSON per shape key, so measured picks survive process restarts — and CI
+restores the directory across workflow runs with ``actions/cache`` instead
+of re-measuring every run. Corrupt or unreadable entries are ignored and
+re-tuned.
 """
 from __future__ import annotations
 
@@ -39,15 +39,18 @@ import os
 import time
 from typing import Dict, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
-N_BLOCK_CANDIDATES = (64, 128, 256, 512, 1024)
+from repro.common.device import REPO_ROOT
+
+N_BLOCK_CANDIDATES = (128, 256, 512, 1024)
 R_BLOCK_CANDIDATES = (8, 32, 64, 128, 256, 512)
 
-# ~16 MB VMEM per core; half of it as the tile budget leaves room for the
-# compiler's double buffering of streamed inputs.
-VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+# The v5e compiler's default scoped VMEM. ``tile_vmem_bytes`` is fitted to
+# what compiles: against this budget every config it admits compiled for a
+# described v5e (f32 and bf16, d 64..8192, n_block 128..2048, R 1..512),
+# and it rejects a few that would also have compiled.
+VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,8 +77,7 @@ def cache_dir() -> Optional[str]:
     d = os.environ.get("REPRO_AUTOTUNE_CACHE_DIR")
     if d == "":
         return None
-    return d or os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                             "pairwise-autotune")
+    return d or str(REPO_ROOT / ".autotune_cache")
 
 
 def _disk_path(key: Tuple[int, int, str, str]) -> Optional[str]:
@@ -90,7 +92,8 @@ def _disk_path(key: Tuple[int, int, str, str]) -> Optional[str]:
 # being trusted across a code change that invalidated them.
 # format 2: the round variant joined the key AND the filename — format-1
 # entries predate the gated round and could alias both variants
-_DISK_FORMAT = 2
+# format 3: lane-major kernels, 128-multiple n_block, the 16 MiB VMEM model
+_DISK_FORMAT = 3
 
 
 def _disk_load(key: Tuple[int, int, str, str]) -> Optional[BlockChoice]:
@@ -159,13 +162,17 @@ def round_hbm_bytes(n: int, d: int, dtype_bytes: float, n_block: int,
 
 def tile_vmem_bytes(d: int, dtype_bytes: float, n_block: int,
                     r_block: int) -> float:
-    """Per-grid-step VMEM: row tile (input dtype + f32 upcast), center tile,
-    the (n_block, Rp) distance matrix, and the (N,) vector tiles."""
+    """Per-grid-step VMEM: the row tile in its dtype, its f32 upcast
+    (none for f32 input) and f32 square, the f32 center tile
+    double-buffered plus its square, four (Rp, n_block) f32 distance
+    temporaries, and eight (1, n_block) f32 vector rows padded to 8
+    sublanes."""
     rp = _pad_to(max(r_block, 1), 8)
-    row = n_block * d * (dtype_bytes + 4)
-    cen = rp * d * (dtype_bytes + 4)
-    dist = n_block * rp * 4
-    vecs = 4 * n_block * 4                # mind in/out, weights, iota masks
+    upcast = 4 if dtype_bytes < 4 else 0
+    row = n_block * d * (dtype_bytes + upcast + 4)
+    cen = 3 * rp * d * 4
+    dist = 4 * rp * n_block * 4
+    vecs = 8 * 8 * n_block * 4
     return row + cen + dist + vecs
 
 
@@ -173,13 +180,6 @@ def _feasible(n: int, d: int, dtype_bytes: float, n_block: int,
               r_block: int) -> bool:
     return tile_vmem_bytes(d, dtype_bytes, n_block, r_block) \
         <= VMEM_BUDGET_BYTES
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 def _measure_round(x, n_block: int, variant: str = "round",
@@ -214,11 +214,17 @@ def _measure_round(x, n_block: int, variant: str = "round",
 
 
 def autotune_blocks(n: int, d: int, dtype=jnp.float32,
-                    measure: Optional[bool] = None,
+                    measure: bool = False,
                     variant: str = "round") -> BlockChoice:
     """Best (n_block, r_block) for an (N, d) pool of ``dtype``; cached
     per round ``variant`` ("round" = plain fused, "gated" =
-    block-masked)."""
+    block-masked).
+
+    ``measure=True`` re-ranks the feasible n_block shortlist by wall clock
+    on the default device; it runs kernels, so call it outside any trace.
+    The served path never measures: the unlabeled pool's N changes with
+    every label, and each new N would re-time every candidate inside a
+    query."""
     if variant not in VARIANTS:
         raise ValueError(f"variant must be one of {VARIANTS}, "
                          f"got {variant!r}")
@@ -227,8 +233,6 @@ def autotune_blocks(n: int, d: int, dtype=jnp.float32,
     if key in _CACHE:
         return _CACHE[key]
     dtype_bytes = float(dt.itemsize)
-    if measure is None:
-        measure = _on_tpu()
     # a persisted winner is reused when it is at least as informed as what
     # this process would produce: measured entries always, model-only
     # entries only for a model-only run (a TPU run re-measures and

@@ -38,6 +38,14 @@ Two kernels live here:
     The R-center ("multi-center") form is what makes the Core-Set
     warm-start cheap: M labeled centers fold into ceil(M / R) pool passes
     instead of one pass per center (see ``ops.warm_start_min_dist``).
+
+Layout. Distances are computed transposed, as an (R, N_b) tile with pool
+rows on the 128 lanes, so every per-row quantity (min-dist, weights, the
+argmin) is a lane-major (1, N_b) row and the (N,) vectors travel as
+(1, N) arrays in blocks of (1, N_b). On the chip N_b is therefore a
+multiple of 128 whenever it is smaller than N. Per-block (max, argmax)
+partials are written broadcast over one 128-lane group of a (1, 128 *
+n_blocks) array, the smallest block the TPU tiling allows.
 """
 from __future__ import annotations
 
@@ -48,9 +56,49 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 BIG = 3.4e38
+LANES = 128
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _sq_dists_t(x, c):
+    """(Mc, Nb) squared distances from the (Mc, d) centers to the (Nb, d)
+    pool rows, pool rows on lanes. The row norms come out of the MXU as a
+    (1, Nb) row (a ones-vector matmul), so nothing is relaid out."""
+    x = x.astype(jnp.float32)
+    c = c.astype(jnp.float32)
+    nt = (((1,), (1,)), ((), ()))
+    xc = jax.lax.dot_general(c, x, nt, precision=_HIGHEST,
+                             preferred_element_type=jnp.float32)
+    ones = jnp.ones((8, x.shape[1]), jnp.float32)
+    x2 = jax.lax.dot_general(ones, x * x, nt, precision=_HIGHEST,
+                             preferred_element_type=jnp.float32)[0:1]
+    c2 = jnp.sum(c * c, axis=1, keepdims=True)
+    return jnp.maximum(x2 + c2 - 2.0 * xc, 0.0)
+
+
+def _block_max(mval, offset, bmax_ref, barg_ref):
+    """Write the (1, Nb) row's max and the lowest lane index holding it
+    (plus ``offset``) broadcast over the block's partial lanes."""
+    m = jnp.max(mval, axis=1, keepdims=True)                      # (1, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, mval.shape, 1)
+    first = jnp.min(jnp.where(mval == m, lane, mval.shape[1]), axis=1,
+                    keepdims=True)
+    bmax_ref[...] = jnp.broadcast_to(m, bmax_ref.shape)
+    barg_ref[...] = jnp.broadcast_to(first + offset, barg_ref.shape)
+
+
+def _partials(bmax, barg):
+    """Reduce the per-block partials: the first block holding the max."""
+    bmax = bmax.reshape(-1, LANES)[:, 0]
+    barg = barg.reshape(-1, LANES)[:, 0]
+    win = jnp.argmax(bmax)
+    return barg[win], bmax[win]
+
+
+def _row_vec(v, n_pad):
+    """(N,) -> (1, N + n_pad) f32 lane-major row."""
+    return jnp.pad(v.astype(jnp.float32), (0, n_pad)).reshape(1, -1)
 
 
 def _kernel(x_ref, c_ref, mind_ref, argm_ref, acc_d, acc_i, *, nm: int,
@@ -62,18 +110,14 @@ def _kernel(x_ref, c_ref, mind_ref, argm_ref, acc_d, acc_i, *, nm: int,
         acc_d[...] = jnp.full_like(acc_d, BIG)
         acc_i[...] = jnp.zeros_like(acc_i)
 
-    x = x_ref[...].astype(jnp.float32)                  # (Nb, d)
-    c = c_ref[...].astype(jnp.float32)                  # (Mb, d)
-    x2 = jnp.sum(x * x, axis=-1, keepdims=True)         # (Nb, 1)
-    c2 = jnp.sum(c * c, axis=-1)                        # (Mb,)
-    xc = jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    d = jnp.maximum(x2 + c2[None, :] - 2.0 * xc, 0.0)   # (Nb, Mb)
-    col = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1) + j * m_block
-    d = jnp.where(col < m, d, BIG)
-
-    bmin = jnp.min(d, axis=-1)
-    barg = jnp.argmin(d, axis=-1).astype(jnp.int32) + j * m_block
+    d = _sq_dists_t(x_ref[...], c_ref[...])              # (Mb, Nb)
+    row = jax.lax.broadcasted_iota(jnp.int32, d.shape, 0) + j * m_block
+    d = jnp.where(row < m, d, BIG)
+    bmin = jnp.min(d, axis=0, keepdims=True)             # (1, Nb)
+    # first index of the min: argmin's tie rule, across center blocks too
+    # (a later block must be strictly better to replace it)
+    barg = jnp.min(jnp.where(d == bmin, row, jnp.iinfo(jnp.int32).max),
+                   axis=0, keepdims=True)
     better = bmin < acc_d[...]
     acc_i[...] = jnp.where(better, barg, acc_i[...])
     acc_d[...] = jnp.where(better, bmin, acc_d[...])
@@ -90,7 +134,7 @@ def pairwise_min_argmin_pallas(x, c, *, n_block: int = 256,
     N, d = x.shape
     M, _ = c.shape
     nb = min(n_block, N)
-    mb = min(m_block, M)
+    mb = min(m_block, -(-M // 8) * 8)
     nn = -(-N // nb)
     nm = -(-M // mb)
     Np, Mp = nn * nb, nm * mb
@@ -106,41 +150,36 @@ def pairwise_min_argmin_pallas(x, c, *, n_block: int = 256,
             pl.BlockSpec((mb, d), lambda i, j: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((nb,), lambda i, j: (i,)),
-            pl.BlockSpec((nb,), lambda i, j: (i,)),
+            pl.BlockSpec((1, nb), lambda i, j: (0, i)),
+            pl.BlockSpec((1, nb), lambda i, j: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Np,), jnp.float32),
-            jax.ShapeDtypeStruct((Np,), jnp.int32),
+            jax.ShapeDtypeStruct((1, Np), jnp.float32),
+            jax.ShapeDtypeStruct((1, Np), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((nb,), jnp.float32),
-            pltpu.VMEM((nb,), jnp.int32),
+            pltpu.VMEM((1, nb), jnp.float32),
+            pltpu.VMEM((1, nb), jnp.int32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, c)
-    return mind[:N], argm[:N]
+    return mind[0, :N], argm[0, :N]
 
 
 def _greedy_kernel(x_ref, mind_ref, c_ref, sel_ref, w_ref,
                    nmind_ref, bmax_ref, barg_ref, *, n: int, r: int,
                    n_block: int):
     i = pl.program_id(0)
-    x = x_ref[...].astype(jnp.float32)                  # (Nb, d)
-    c = c_ref[...].astype(jnp.float32)                  # (Rp, d)
-    x2 = jnp.sum(x * x, axis=-1, keepdims=True)         # (Nb, 1)
-    c2 = jnp.sum(c * c, axis=-1)                        # (Rp,)
-    xc = jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    d = jnp.maximum(x2 + c2[None, :] - 2.0 * xc, 0.0)   # (Nb, Rp)
-    col = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
-    d = jnp.where(col < r, d, BIG)
+    d = _sq_dists_t(x_ref[...], c_ref[...])              # (Rp, Nb)
+    row = jax.lax.broadcasted_iota(jnp.int32, d.shape, 0)
+    d = jnp.where(row < r, d, BIG)
 
-    nm = jnp.minimum(mind_ref[...], jnp.min(d, axis=-1))
-    gid2 = jax.lax.broadcasted_iota(jnp.int32, d.shape, 0) + i * n_block
-    hit = jnp.any(gid2 == sel_ref[...][None, :], axis=-1)
+    nm = jnp.minimum(mind_ref[...], jnp.min(d, axis=0, keepdims=True))
+    gid = jax.lax.broadcasted_iota(jnp.int32, nm.shape, 1) + i * n_block
+    hit = jnp.max(jnp.where(gid == sel_ref[...], 1, 0), axis=0,
+                  keepdims=True) > 0                     # (1, Nb)
     nm = jnp.where(hit, -1.0, nm)
     nmind_ref[...] = nm
 
@@ -149,11 +188,9 @@ def _greedy_kernel(x_ref, mind_ref, c_ref, sel_ref, w_ref,
     # ties (first-index wins) against legitimate zero-score rows, so a
     # masked row could win the argmax. -BIG can never tie a real score.
     score = nm * w_ref[...]
-    valid = (gid2[:, 0] < n) & jnp.logical_not(nm < 0.0)
-    mval = jnp.where(valid, score, -BIG)
-    bmax_ref[...] = jnp.max(mval).reshape(1)
-    barg_ref[...] = (jnp.argmax(mval).astype(jnp.int32)
-                     + i * n_block).reshape(1)
+    valid = (gid < n) & jnp.logical_not(nm < 0.0)
+    _block_max(jnp.where(valid, score, -BIG), i * n_block, bmax_ref,
+               barg_ref)
 
 
 def greedy_round_pallas(x, mind, centers, sel_idx, weights=None, *,
@@ -185,40 +222,39 @@ def greedy_round_pallas(x, mind, centers, sel_idx, weights=None, *,
     Rp = -(-R // 8) * 8
     if Np != N:
         x = jnp.pad(x, ((0, Np - N), (0, 0)))
-        mind = jnp.pad(mind, (0, Np - N))
     if Rp != R:
         centers = jnp.pad(centers, ((0, Rp - R), (0, 0)))
         sel_idx = jnp.pad(sel_idx, (0, Rp - R), constant_values=-1)
-    w = (jnp.ones((Np,), jnp.float32) if weights is None
-         else jnp.pad(weights.astype(jnp.float32), (0, Np - N)))
+    w = (jnp.ones((1, Np), jnp.float32) if weights is None
+         else _row_vec(weights, Np - N))
     nmind, bmax, barg = pl.pallas_call(
         functools.partial(_greedy_kernel, n=N, r=R, n_block=nb),
         grid=(nn,),
         in_specs=[
             pl.BlockSpec((nb, d), lambda i: (i, 0)),
-            pl.BlockSpec((nb,), lambda i: (i,)),
+            pl.BlockSpec((1, nb), lambda i: (0, i)),
             pl.BlockSpec((Rp, d), lambda i: (0, 0)),
-            pl.BlockSpec((Rp,), lambda i: (0,)),
-            pl.BlockSpec((nb,), lambda i: (i,)),
+            pl.BlockSpec((Rp, 1), lambda i: (0, 0)),
+            pl.BlockSpec((1, nb), lambda i: (0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((nb,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
+            pl.BlockSpec((1, nb), lambda i: (0, i)),
+            pl.BlockSpec((1, LANES), lambda i: (0, i)),
+            pl.BlockSpec((1, LANES), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Np,), jnp.float32),
-            jax.ShapeDtypeStruct((nn,), jnp.float32),
-            jax.ShapeDtypeStruct((nn,), jnp.int32),
+            jax.ShapeDtypeStruct((1, Np), jnp.float32),
+            jax.ShapeDtypeStruct((1, nn * LANES), jnp.float32),
+            jax.ShapeDtypeStruct((1, nn * LANES), jnp.int32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(x, mind.astype(jnp.float32), centers.astype(jnp.float32),
-      sel_idx.astype(jnp.int32), w)
+    )(x, _row_vec(mind, Np - N), centers.astype(jnp.float32),
+      sel_idx.astype(jnp.int32).reshape(Rp, 1), w)
     # O(N / N_b) reduction over block partials picks the next center.
-    win = jnp.argmax(bmax)
-    return nmind[:N], barg[win], bmax[win]
+    nxt, score = _partials(bmax, barg)
+    return nmind[0, :N], nxt, score
 
 
 def _gated_kernel(live_ref, pend_ref, x_ref, mind_ref, c_ref, w_ref,
@@ -230,34 +266,25 @@ def _gated_kernel(live_ref, pend_ref, x_ref, mind_ref, c_ref, w_ref,
 
     @pl.when(live)
     def _eval():
-        x = x_ref[...].astype(jnp.float32)              # (Nb, d)
-        c = c_ref[...].astype(jnp.float32)              # (Rp, d)
-        x2 = jnp.sum(x * x, axis=-1, keepdims=True)
-        c2 = jnp.sum(c * c, axis=-1)
-        xc = jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        d = jnp.maximum(x2 + c2[None, :] - 2.0 * xc, 0.0)
-        col = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
+        d = _sq_dists_t(x_ref[...], c_ref[...])          # (Rp, Nb)
+        row = jax.lax.broadcasted_iota(jnp.int32, d.shape, 0)
         # catch-up masking: this block already folded centers
         # [0, pend[i]) in earlier rounds; fold only the queue's tail
-        d = jnp.where((col >= pend_ref[i]) & (col < r), d, BIG)
-        nm = jnp.minimum(mind, jnp.min(d, axis=-1))
+        d = jnp.where((row >= pend_ref[i]) & (row < r), d, BIG)
+        nm = jnp.minimum(mind, jnp.min(d, axis=0, keepdims=True))
         nmind_ref[...] = nm
-        gid = (jax.lax.broadcasted_iota(jnp.int32, (n_block, 1), 0)[:, 0]
-               + i * n_block)
+        gid = jax.lax.broadcasted_iota(jnp.int32, nm.shape, 1) + i * n_block
         score = nm * w_ref[...]
         valid = (gid < n) & jnp.logical_not(nm < 0.0)
-        mval = jnp.where(valid, score, -BIG)
-        bmax_ref[...] = jnp.max(mval).reshape(1)
-        barg_ref[...] = (jnp.argmax(mval).astype(jnp.int32)
-                         + i * n_block).reshape(1)
+        _block_max(jnp.where(valid, score, -BIG), i * n_block, bmax_ref,
+                   barg_ref)
 
     @pl.when(jnp.logical_not(live))
     def _skip():
         # dead block: min-dists pass through, partials can never win
         nmind_ref[...] = mind
-        bmax_ref[...] = jnp.full((1,), -BIG, jnp.float32)
-        barg_ref[...] = jnp.full((1,), i * n_block, jnp.int32)
+        bmax_ref[...] = jnp.full(bmax_ref.shape, -BIG, jnp.float32)
+        barg_ref[...] = jnp.full(barg_ref.shape, i * n_block, jnp.int32)
 
 
 def gated_greedy_round_pallas(x, mind, centers, block_live, block_pending,
@@ -288,11 +315,10 @@ def gated_greedy_round_pallas(x, mind, centers, block_live, block_pending,
             f"{block_live.shape[0]}/{block_pending.shape[0]} for {nn}")
     if Np != N:
         x = jnp.pad(x, ((0, Np - N), (0, 0)))
-        mind = jnp.pad(mind, (0, Np - N))
     if Rp != R:
         centers = jnp.pad(centers, ((0, Rp - R), (0, 0)))
-    w = (jnp.ones((Np,), jnp.float32) if weights is None
-         else jnp.pad(weights.astype(jnp.float32), (0, Np - N)))
+    w = (jnp.ones((1, Np), jnp.float32) if weights is None
+         else _row_vec(weights, Np - N))
     live = block_live.astype(jnp.int32)
     pend = block_pending.astype(jnp.int32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -303,28 +329,28 @@ def gated_greedy_round_pallas(x, mind, centers, block_live, block_pending,
             # for the pool rows the gate pruned
             pl.BlockSpec((nb, d),
                          lambda i, lv, pd: (jnp.where(lv[i] > 0, i, 0), 0)),
-            pl.BlockSpec((nb,), lambda i, lv, pd: (i,)),
+            pl.BlockSpec((1, nb), lambda i, lv, pd: (0, i)),
             pl.BlockSpec((Rp, d), lambda i, lv, pd: (0, 0)),
-            pl.BlockSpec((nb,), lambda i, lv, pd: (i,)),
+            pl.BlockSpec((1, nb), lambda i, lv, pd: (0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((nb,), lambda i, lv, pd: (i,)),
-            pl.BlockSpec((1,), lambda i, lv, pd: (i,)),
-            pl.BlockSpec((1,), lambda i, lv, pd: (i,)),
+            pl.BlockSpec((1, nb), lambda i, lv, pd: (0, i)),
+            pl.BlockSpec((1, LANES), lambda i, lv, pd: (0, i)),
+            pl.BlockSpec((1, LANES), lambda i, lv, pd: (0, i)),
         ],
     )
     nmind, bmax, barg = pl.pallas_call(
         functools.partial(_gated_kernel, n=N, r=R, n_block=nb),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((Np,), jnp.float32),
-            jax.ShapeDtypeStruct((nn,), jnp.float32),
-            jax.ShapeDtypeStruct((nn,), jnp.int32),
+            jax.ShapeDtypeStruct((1, Np), jnp.float32),
+            jax.ShapeDtypeStruct((1, nn * LANES), jnp.float32),
+            jax.ShapeDtypeStruct((1, nn * LANES), jnp.int32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(live, pend, x, mind.astype(jnp.float32),
+    )(live, pend, x, _row_vec(mind, Np - N),
       centers.astype(jnp.float32), w)
-    win = jnp.argmax(bmax)
-    return nmind[:N], barg[win], bmax[win]
+    nxt, score = _partials(bmax, barg)
+    return nmind[0, :N], nxt, score

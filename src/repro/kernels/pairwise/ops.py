@@ -16,16 +16,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.device import on_tpu
 from repro.kernels.pairwise import autotune, ref
 from repro.kernels.pairwise.kernel import (BIG, greedy_round_pallas,
                                            pairwise_min_argmin_pallas)
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 # ------------------------------------------------------- op accounting ----
@@ -85,7 +79,7 @@ def record_pool_rows(n: int) -> None:
 @functools.partial(jax.jit, static_argnames=("impl",))
 def _pairwise_min_and_argmin(x, c, impl: str):
     if impl == "auto":
-        impl = "pallas" if _on_tpu() else "ref"
+        impl = "pallas" if on_tpu() else "ref"
     if impl == "ref":
         return ref.pairwise_min_and_argmin_ref(x, c)
     return pairwise_min_argmin_pallas(x, c, interpret=(impl == "interpret"))
@@ -133,7 +127,7 @@ def sq_dist_to_center(x, center):
 def _greedy_round(x, mind, centers, sel_idx, weights, impl: str,
                   n_block: int):
     if impl == "auto":
-        impl = "pallas" if _on_tpu() else "ref"
+        impl = "pallas" if on_tpu() else "ref"
     if impl == "ref":
         return ref.greedy_round_ref(x, mind, centers, sel_idx, weights)
     return greedy_round_pallas(x, mind, centers, sel_idx, weights,
@@ -194,7 +188,7 @@ def greedy_round_unfused(x, mind, center, sel_idx):
 def _gated_greedy_round(x, mind, centers, block_live, block_pending,
                         weights, impl: str, n_block: int):
     if impl == "auto":
-        impl = "pallas" if _on_tpu() else "ref"
+        impl = "pallas" if on_tpu() else "ref"
     if impl == "ref":
         return ref.gated_greedy_round_ref(x, mind, centers, block_live,
                                           block_pending, weights,

@@ -1,12 +1,13 @@
 """Pallas TPU kernel: fused uncertainty scores over the vocab axis.
 
 One streaming pass over (R_b, V_b) VMEM tiles of the logits, carrying
-per-row online statistics in VMEM scratch across the sequential vocab grid
-axis: running max m1, runner-up m2, shifted sum-exp, and shifted
-sum(l * exp(l)) — everything LC/MC/RC/ES need, with no (N, V) softmax ever
-materialized in HBM. This is the AL serving hot-spot when the scorer is an
-LLM (V up to 256k): arithmetic intensity is O(1) per logit, so the kernel's
-job is to keep the pass memory-bound at exactly one HBM read of the logits.
+per-row online statistics as (R_b, 1) columns in VMEM scratch across the
+sequential vocab grid axis: running max m1, runner-up m2, shifted sum-exp,
+and shifted sum(l * exp(l)) — everything LC/MC/RC/ES need, with no (N, V)
+softmax ever materialized in HBM. This is the AL serving hot-spot when the
+scorer is an LLM (V up to 256k): arithmetic intensity is O(1) per logit, so
+the kernel's job is to keep the pass memory-bound at exactly one HBM read of
+the logits. The four scores leave as one (R_b, 4) block per row block.
 
 Grid: (row_blocks, vocab_blocks); rows parallel, vocab sequential
 (dimension_semantics = ("parallel", "arbitrary")).
@@ -19,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels import compat
 
 NEG = -1e30
 
@@ -41,13 +40,12 @@ def _kernel(logits_ref, out_ref, m1, m2, se, sl, *, nv: int, v: int,
     col = jax.lax.broadcasted_iota(jnp.int32, lg.shape, 1) + j * v_block
     lg = jnp.where(col < v, lg, NEG)
 
-    bm1 = jnp.max(lg, axis=-1)                          # block max
-    # block runner-up: max over the block with the argmax knocked out
-    is_max = lg == bm1[:, None]
-    # knock out exactly one occurrence (leftmost) of the max
-    first_max = jnp.cumsum(is_max.astype(jnp.int32), axis=-1) == 1
-    knock = is_max & first_max
-    bm2 = jnp.max(jnp.where(knock, NEG, lg), axis=-1)
+    bm1 = jnp.max(lg, axis=-1, keepdims=True)           # block max (R, 1)
+    # block runner-up: max over the block with exactly one occurrence (the
+    # leftmost) of the max knocked out
+    first = jnp.min(jnp.where(lg == bm1, col, jnp.iinfo(jnp.int32).max),
+                    axis=-1, keepdims=True)
+    bm2 = jnp.max(jnp.where(col == first, NEG, lg), axis=-1, keepdims=True)
 
     om1, om2 = m1[...], m2[...]
     nm1 = jnp.maximum(om1, bm1)
@@ -55,10 +53,10 @@ def _kernel(logits_ref, out_ref, m1, m2, se, sl, *, nv: int, v: int,
     nm2 = jnp.maximum(jnp.maximum(jnp.minimum(om1, bm1), om2), bm2)
 
     scale = jnp.exp(om1 - nm1)                          # rescale old sums
-    e = jnp.exp(lg - nm1[:, None])
+    e = jnp.exp(lg - nm1)
     e = jnp.where(col < v, e, 0.0)
-    se[...] = se[...] * scale + jnp.sum(e, axis=-1)
-    sl[...] = sl[...] * scale + jnp.sum(e * lg, axis=-1)
+    se[...] = se[...] * scale + jnp.sum(e, axis=-1, keepdims=True)
+    sl[...] = sl[...] * scale + jnp.sum(e * lg, axis=-1, keepdims=True)
     m1[...] = nm1
     m2[...] = nm2
 
@@ -68,10 +66,12 @@ def _kernel(logits_ref, out_ref, m1, m2, se, sl, *, nv: int, v: int,
         p1 = jnp.exp(m1[...] - lse)
         p2 = jnp.exp(m2[...] - lse)
         ent = lse - sl[...] / jnp.maximum(se[...], 1e-30)
-        out_ref[0, ...] = 1.0 - p1                      # lc
-        out_ref[1, ...] = -(p1 - p2)                    # mc
-        out_ref[2, ...] = p2 / jnp.maximum(p1, 1e-12)   # rc
-        out_ref[3, ...] = ent                           # es
+        # (R, 4) = [lc, mc, rc, es] columns, assembled by lane select
+        k = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+        out_ref[...] = jnp.where(
+            k == 0, 1.0 - p1, jnp.where(
+                k == 1, -(p1 - p2), jnp.where(
+                    k == 2, p2 / jnp.maximum(p1, 1e-12), ent)))
 
 
 def uncertainty_stats_pallas(logits, *, row_block: int = 256,
@@ -90,16 +90,11 @@ def uncertainty_stats_pallas(logits, *, row_block: int = 256,
         functools.partial(_kernel, nv=nv, v=V, v_block=vb),
         grid=(nr, nv),
         in_specs=[pl.BlockSpec((rb, vb), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((4, rb), lambda i, j: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((4, Np), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((rb,), jnp.float32),
-            pltpu.VMEM((rb,), jnp.float32),
-            pltpu.VMEM((rb,), jnp.float32),
-            pltpu.VMEM((rb,), jnp.float32),
-        ],
-        compiler_params=compat.CompilerParams(
+        out_specs=pl.BlockSpec((rb, 4), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Np, 4), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((rb, 1), jnp.float32) for _ in range(4)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(logits)
-    return out[:, :N]
+    return out[:N].T

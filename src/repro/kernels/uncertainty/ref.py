@@ -1,4 +1,5 @@
-"""Pure-jnp oracle for fused uncertainty scoring over logits."""
+"""Pure-jnp oracles for fused uncertainty scoring: over logits, and the
+closed forms over softmax probabilities the served strategies score."""
 from __future__ import annotations
 
 import jax
@@ -28,3 +29,24 @@ def uncertainty_stats_ref(logits):
 
 def uncertainty_scores_ref(logits, kind: str):
     return uncertainty_stats_ref(logits)[kind]
+
+
+# floor under probabilities before a log: keeps log(0) finite, and far
+# below any score difference f32 can show
+TINY = 1e-30
+
+
+def probs_scores_ref(probs, kind: str):
+    """probs: (N, C) softmax rows -> (N,) scores (Settles' conventions):
+    lc = 1 - p1; mc = -(p1 - p2); rc = p2 / p1; es = -sum p log p."""
+    if kind == "lc":
+        return 1.0 - jnp.max(probs, axis=-1)
+    if kind == "es":
+        p = jnp.clip(probs, 1e-12, 1.0)
+        return -jnp.sum(p * jnp.log(p), axis=-1)
+    top2 = jax.lax.top_k(probs, 2)[0]
+    if kind == "mc":
+        return -(top2[..., 0] - top2[..., 1])
+    if kind == "rc":
+        return top2[..., 1] / jnp.maximum(top2[..., 0], 1e-12)
+    raise KeyError(f"unknown uncertainty score {kind!r}")

@@ -7,21 +7,10 @@ platform devices via XLA_FLAGS before any jax import.
 """
 from __future__ import annotations
 
-import contextlib
-
 import numpy as np
 
 import jax
 from jax.sharding import Mesh
-
-
-def set_mesh(mesh: Mesh):
-    """``jax.set_mesh`` where it exists; otherwise a no-op context (older
-    jax — every shard_map in this repo passes ``mesh=`` explicitly, so the
-    ambient-mesh context is optional)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return contextlib.nullcontext(mesh)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -98,8 +98,10 @@ class ResNetBackend(FeatureBackend):
         if rng is None:
             rng = jax.random.PRNGKey(42)
         self.params = resnet_lib.init_resnet(self.cfg, rng)
+        # params are arguments, not closed-over constants: baked in, the
+        # ResNet-18 weights double the compiled program and its cache entry
         self._feat = jax.jit(
-            lambda x: resnet_lib.resnet_features(self.params, self.cfg, x))
+            lambda p, x: resnet_lib.resnet_features(p, self.cfg, x))
 
     def preprocess(self, raw: np.ndarray) -> np.ndarray:
         x = np.asarray(raw, np.float32)
@@ -112,7 +114,7 @@ class ResNetBackend(FeatureBackend):
         return np.where(mx > 1.5, x / 255.0, x)
 
     def features(self, batch: np.ndarray) -> np.ndarray:
-        return np.asarray(self._feat(jnp.asarray(batch)))
+        return np.asarray(self._feat(self.params, jnp.asarray(batch)))
 
 
 class MLPBackend(FeatureBackend):
@@ -124,12 +126,12 @@ class MLPBackend(FeatureBackend):
             rng = jax.random.PRNGKey(7)
         k1, k2 = jax.random.split(rng)
         self.in_dim = in_dim
-        self.w1 = jax.random.normal(k1, (in_dim, 128)) / np.sqrt(in_dim)
-        self.w2 = jax.random.normal(k2, (128, feat_dim)) / np.sqrt(128)
+        self.params = (jax.random.normal(k1, (in_dim, 128)) / np.sqrt(in_dim),
+                       jax.random.normal(k2, (128, feat_dim)) / np.sqrt(128))
         self.num_classes = num_classes
         self.feat_dim = feat_dim
         self._feat = jax.jit(
-            lambda x: jnp.tanh(jnp.tanh(x @ self.w1) @ self.w2))
+            lambda p, x: jnp.tanh(jnp.tanh(x @ p[0]) @ p[1]))
 
     def preprocess(self, raw: np.ndarray) -> np.ndarray:
         x = np.asarray(raw, np.float32)
@@ -146,7 +148,8 @@ class MLPBackend(FeatureBackend):
         return x
 
     def features(self, batch: np.ndarray) -> np.ndarray:
-        return np.asarray(self._feat(jnp.asarray(batch, jnp.float32)))
+        return np.asarray(self._feat(self.params,
+                                     jnp.asarray(batch, jnp.float32)))
 
 
 class TransformerBackend(FeatureBackend):
@@ -193,15 +196,15 @@ class TransformerBackend(FeatureBackend):
         self.params = blockwise_lib.init_encoder(
             self.cfg, rng, self.input_dim if modality == "audio" else None)
 
-        def forward(batch):
+        def forward(params, batch):
             if self.modality == "text":
-                x = blockwise_lib.embed_tokens(self.cfg, self.params, batch)
+                x = blockwise_lib.embed_tokens(self.cfg, params, batch)
                 mask = batch >= 0
             else:
-                x = blockwise_lib.embed_frames(self.params, batch)
+                x = blockwise_lib.embed_frames(params, batch)
                 mask = jnp.ones(batch.shape[:2], bool)
             h = blockwise_lib.blockwise_encode(
-                self.cfg, self.params, x, block=self.block_size,
+                self.cfg, params, x, block=self.block_size,
                 kv_chunk=self.kv_chunk, impl=self.impl)
             return blockwise_lib.pool_hidden(h, mask, self.pooling)
 
@@ -235,7 +238,7 @@ class TransformerBackend(FeatureBackend):
         return out
 
     def features(self, batch: np.ndarray) -> np.ndarray:
-        return np.asarray(self._feat(jnp.asarray(batch)))
+        return np.asarray(self._feat(self.params, jnp.asarray(batch)))
 
     def activation_accounting(self, batch: int,
                               seq_len: Optional[int] = None) -> dict:

@@ -115,7 +115,6 @@ class ALServiceConfig:
     model_pooling: str = "mean"
     model_modality: str = "text"
     model_input_dim: int = 0
-    device: str = "CPU"
     protocol: str = "tcp"
     host: str = "127.0.0.1"
     port: int = 60035
@@ -208,13 +207,10 @@ class ALServiceConfig:
     # drains; "shed" = raise ServerOverloaded (retryable; the TCP
     # PushTicket fails with it, nothing was enqueued)
     ingest_policy: str = "block"
-    # shard-worker runtime (distributed.worker, replicas > 1): "thread"
-    # runs each shard's rounds on a dedicated supervised lane thread;
-    # "process" additionally pairs each lane with an OS worker process
-    # that executes the registered embed jobs (true process isolation for
-    # the heavy step; closures stay on the lane thread)
-    worker_backend: str = "thread"
-    # a shard task past this wall-clock is presumed a dead worker: the
+    # shard-worker runtime (distributed.worker, replicas > 1): each shard's
+    # rounds run on a dedicated supervised lane thread, pinned to a device
+    # round-robin on a multi-device host.
+    # A shard task past this wall-clock is presumed a dead worker: the
     # lane restarts, the shard recovers (re-embed from raw + content
     # keys), and the task retries
     worker_timeout_s: float = 30.0
@@ -244,7 +240,6 @@ class ALServiceConfig:
             model_pooling=model.get("pooling", "mean"),
             model_modality=model.get("modality", "text"),
             model_input_dim=int(model.get("input_dim", 0)),
-            device=str(al.get("device", "CPU")),
             protocol=worker.get("protocol", "tcp"),
             host=worker.get("host", "127.0.0.1"),
             port=int(worker.get("port", 60035)),
@@ -265,7 +260,6 @@ class ALServiceConfig:
             prefilter_min_rows=int(al.get("prefilter_min_rows", 256)),
             shard_ram_bytes=int(worker.get("shard_ram_bytes", 0)),
             shard_spill_dir=worker.get("shard_spill_dir"),
-            worker_backend=worker.get("backend", "thread"),
             worker_timeout_s=float(worker.get("timeout_s", 30.0)),
             worker_retries=int(worker.get("retries", 2)),
             worker_backoff_s=float(worker.get("backoff_s", 0.05)),

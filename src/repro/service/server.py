@@ -59,8 +59,6 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
-import dataclasses
-
 from repro.core.agent.controller import run_pshea
 from repro.distributed.worker import (PhaseFailureInjector, ShardWorkerPool)
 from repro.core.prefilter import PrefilterConfig, maintain_summary
@@ -608,10 +606,7 @@ class ALSession:
             for s in range(0, len(missing), bs):
                 grp = missing[s:s + bs]
                 raw = np.stack([np.asarray(self._raw[k]) for k in grp])
-                feats = self.server._embed_chunk(
-                    raw, bs, shard_hint=(replica_of(grp[0], self.replicas)
-                                         if self.replicas > 1 else 0),
-                    backend=backend)
+                feats = self.server._embed_chunk(raw, bs, backend=backend)
                 for k, f in zip(grp, feats):
                     f = np.asarray(f)
                     cache.put(k, f)
@@ -1291,10 +1286,6 @@ class ALServer:
             config = (ALServiceConfig.from_yaml(config_path)
                       if config_path else ALServiceConfig())
         self.config = config
-        # process-backed embed jobs rebuild the backend from config in the
-        # worker process; only valid when OUR backend came from the same
-        # config (a hand-constructed backend object can't be reproduced)
-        self._backend_from_config = backend is None
         self.backend = (backend if backend is not None
                         else make_backend(config.model_name, config=config))
         self.cache = EmbeddingCache(config.cache_bytes,
@@ -1335,8 +1326,7 @@ class ALServer:
             if self._shard_runtime is None:
                 cfg = self.config
                 self._shard_runtime = ShardWorkerPool(
-                    cfg.replicas, kind=cfg.worker_backend,
-                    timeout_s=cfg.worker_timeout_s,
+                    cfg.replicas, timeout_s=cfg.worker_timeout_s,
                     max_retries=cfg.worker_retries,
                     backoff_s=cfg.worker_backoff_s,
                     injector=self.failure_injector)
@@ -1397,8 +1387,16 @@ class ALServer:
         # features are bitwise independent of how pushes were chunked or
         # interleaved (the batch-insensitivity contract standing queries
         # and the content-addressed cache rely on)
-        batcher = DynamicBatcher(self._infer_batch, max_batch=bs,
-                                 pad_to_max=True)
+        # a shard lane pins its device with jax.default_device, which is
+        # thread-local: carry it into the batcher thread that runs the
+        # forward, or every shard would embed on the first device
+        device = jax.config.jax_default_device
+
+        def infer_batch(stacked, n_valid):
+            with jax.default_device(device):
+                return self._infer_batch(stacked, n_valid)
+
+        batcher = DynamicBatcher(infer_batch, max_batch=bs, pad_to_max=True)
 
         def fetch(chunk_items):
             if self.fetch_latency_s:
@@ -1428,21 +1426,10 @@ class ALServer:
             batcher.close()
         return pipe.stats()
 
-    def _embed_chunk(self, raw: np.ndarray, bs: int, *, shard_hint: int,
+    def _embed_chunk(self, raw: np.ndarray, bs: int, *,
                      backend: FeatureBackend) -> np.ndarray:
-        """One canonical embed chunk (preprocess, zero-pad to the one
-        ``bs``-row shape, feature forward). On a process-backed worker
-        runtime the chunk ships to the shard's paired worker process as
-        the registered ``embed_batch`` job — the backend there is rebuilt
-        from the SAME config, so the bytes match the in-process path bit
-        for bit; any other configuration computes inline."""
-        rt = self.shard_runtime()
-        if (rt is not None and rt.kind == "process"
-                and self._backend_from_config):
-            feats = rt.run_job(shard_hint, "embed_batch", {
-                "config": dataclasses.asdict(self.config),
-                "raw": raw, "bs": bs})
-            return np.asarray(feats)
+        """One canonical embed chunk: preprocess, zero-pad to the one
+        ``bs``-row shape, feature forward."""
         x = np.asarray(backend.preprocess(raw))
         n = x.shape[0]
         if n < bs:           # zero-pad to the one canonical shape
@@ -1552,7 +1539,7 @@ class ALServer:
         s["sessions"] = len(self.session_ids())
         rt = self._shard_runtime       # no lazy spin-up just for stats
         s["workers"] = (rt.stats() if rt is not None else {
-            "backend": "inline", "lanes": 0, "tasks": 0, "restarts": 0,
+            "lanes": 0, "tasks": 0, "restarts": 0,
             "straggler_events": 0})
         # transport admission/fairness counters (serve_tcp wires this;
         # absent/in-process -> a disabled placeholder, same shape)

@@ -7,12 +7,11 @@ def _interpret_supported() -> bool:
     try:
         from jax.experimental import pallas  # noqa: F401
         from jax.experimental.pallas import tpu  # noqa: F401
-        from repro.kernels import compat  # noqa: F401
         return True
     except ImportError:
-        # ONLY a missing Pallas skips the lane; any other failure (e.g. a
-        # bug in the compat shim) must surface as loud test errors, not an
-        # all-green all-skipped kernel lane.
+        # ONLY a missing Pallas skips the lane; any other failure must
+        # surface as loud test errors, not an all-green all-skipped kernel
+        # lane.
         return False
 
 

@@ -83,11 +83,11 @@ def test_distributed_topk_matches_global():
     out = _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.selection import distributed_top_k
-        from repro.launch.mesh import make_debug_mesh, set_mesh
+        from repro.launch.mesh import make_debug_mesh
         mesh = make_debug_mesh((8,), ("data",))
         scores = jnp.asarray(np.random.default_rng(0).normal(size=(512,)),
                              jnp.float32)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             idx = distributed_top_k(scores, 16, mesh)
         ref = np.argsort(-np.asarray(scores))[:16]
         assert set(np.asarray(idx).tolist()) == set(ref.tolist())
@@ -101,7 +101,7 @@ def test_distributed_kcenter_covers_clusters():
     out = _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.selection import distributed_k_center
-        from repro.launch.mesh import make_debug_mesh, set_mesh
+        from repro.launch.mesh import make_debug_mesh
         rng = np.random.default_rng(0)
         centers = rng.normal(size=(8, 16)) * 20
         pts = np.concatenate([c + rng.normal(size=(32, 16)) * 0.1
@@ -109,7 +109,7 @@ def test_distributed_kcenter_covers_clusters():
         perm = rng.permutation(256)
         lab = np.repeat(np.arange(8), 32)[perm]
         mesh = make_debug_mesh((8,), ("data",))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             idx = distributed_k_center(jnp.asarray(pts[perm]), 8, mesh)
         got = set(lab[np.asarray(idx)].tolist())
         assert len(got) == 8, got
@@ -126,11 +126,11 @@ def test_distributed_kcenter_weighted():
     out = _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.selection import distributed_k_center
-        from repro.launch.mesh import make_debug_mesh, set_mesh
+        from repro.launch.mesh import make_debug_mesh
         rng = np.random.default_rng(0)
         pts = jnp.asarray(rng.normal(size=(256, 16)), jnp.float32)
         mesh = make_debug_mesh((8,), ("data",))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             base = distributed_k_center(pts, 12, mesh)
             ones = distributed_k_center(pts, 12, mesh,
                                         weights=jnp.ones((256,), jnp.float32))
@@ -154,14 +154,14 @@ def test_compressed_psum_close_to_exact():
         from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.optim.compression import compressed_psum
-        from repro.launch.mesh import make_debug_mesh, set_mesh
+        from repro.launch.mesh import make_debug_mesh
         mesh = make_debug_mesh((8,), ("data",))
         g = jnp.asarray(np.random.default_rng(1).normal(size=(8, 64)),
                         jnp.float32)
         def f(x):
             return compressed_psum(x[0], "data", quantize=True)
         fn = shard_map(f, mesh=mesh, in_specs=P("data", None), out_specs=P())
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             approx = np.asarray(fn(g))
         exact = np.asarray(jnp.sum(g, 0))
         err = np.abs(approx - exact).max() / (np.abs(exact).max() + 1e-9)
@@ -179,7 +179,7 @@ def test_build_cell_small_mesh_compiles():
         import jax
         from repro.configs import get_smoke_config, SHAPES
         import dataclasses
-        from repro.launch.mesh import make_debug_mesh, set_mesh
+        from repro.launch.mesh import make_debug_mesh
         from repro.launch.steps import build_cell
         from repro.roofline import analysis
         cfg = get_smoke_config("qwen3-8b")

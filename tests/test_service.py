@@ -350,24 +350,22 @@ active_learning:
 
 def test_yaml_shard_worker_knobs():
     """The shard-worker runtime knobs round-trip through the YAML subset
-    under ``al_worker``; defaults are thread lanes with a 30s presumed-
-    dead timeout and 2 bounded retries."""
+    under ``al_worker``; defaults are a 30s presumed-dead timeout and 2
+    bounded retries."""
     text = """
 al_worker:
   replicas: 3
-  backend: process
   timeout_s: 5.5
   retries: 4
   backoff_s: 0.25
 """
     cfg = ALServiceConfig.from_dict(parse_yaml(text))
-    assert cfg.worker_backend == "process"
+    assert cfg.replicas == 3
     assert cfg.worker_timeout_s == 5.5
     assert cfg.worker_retries == 4 and cfg.worker_backoff_s == 0.25
     d = ALServiceConfig()
-    assert (d.worker_backend, d.worker_timeout_s,
-            d.worker_retries, d.worker_backoff_s) == ("thread", 30.0, 2,
-                                                      0.05)
+    assert (d.worker_timeout_s, d.worker_retries,
+            d.worker_backoff_s) == (30.0, 2, 0.05)
 
 
 def test_yaml_overload_serving_knobs():
