@@ -122,10 +122,9 @@ def run_micro() -> list:
     # Autotuned launch blocks for this pool shape (what ops.greedy_round /
     # warm_start_min_dist use when n_block / r_block are left unset).
     ch = ops.autotuned_blocks(MICRO_N, MICRO_D, jnp.float32)
-    out.append(row("fig4b_micro/autotune", ch.wall_s * 1e6,
+    out.append(row("fig4b_micro/autotune", 0.0,
                    f"n_block={ch.n_block}|r_block={ch.r_block}"
-                   f"|round_hbm_mb={ch.hbm_bytes / 1e6:.2f}"
-                   f"|source={ch.source}"))
+                   f"|round_hbm_mb={ch.hbm_bytes / 1e6:.2f}"))
 
     # Core-Set warm start: M centers fold into ceil(M / r_block) pool reads
     M, RB = 512, ch.r_block

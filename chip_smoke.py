@@ -41,7 +41,6 @@ PUSH_CHUNK = 1_000
 BATCH = 256
 LC_TIE_TOL = 1e-5           # lc sets may differ only this close to the cut
 RADIUS_RTOL = 1e-3          # coreset covering radius vs the reference
-CLIENT_TIMEOUT_S = 900.0    # one round trip may include a cold compile
 
 
 class PhaseLog:
@@ -86,8 +85,7 @@ def _serve(cfg):
     from repro.service.server import ALServer
     srv = ALServer(cfg)
     rpc = serve_tcp(srv)
-    client = ALClient(url=f"127.0.0.1:{rpc.port}",
-                      timeout_s=CLIENT_TIMEOUT_S)
+    client = ALClient(url=f"127.0.0.1:{rpc.port}")
     return srv, rpc, client
 
 

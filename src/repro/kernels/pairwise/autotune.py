@@ -7,7 +7,7 @@ against VMEM pressure:
     Rows per grid step. The (Rp, d) center tile is re-fetched once per row
     block (its BlockSpec index map is constant), so small ``n_block`` means
     ceil(N / n_block) redundant center reads; large ``n_block`` grows the
-    per-step VMEM footprint (row tile + (n_block, Rp) distance matrix) and
+    per-step VMEM footprint (row tile + (Rp, n_block) distance matrix) and
     eventually spills.
 
 ``r_block``
@@ -17,39 +17,27 @@ against VMEM pressure:
     no longer fit the VMEM budget.
 
 The tuner sweeps both over the same op-accounted HBM model the benchmarks
-use (bytes actually moved per fused round), rejects candidates whose
-per-step VMEM model exceeds the budget, and — with ``measure=True`` —
-re-ranks the model's shortlist by measured wall clock.
-Every candidate is a multiple of 128 rows, the lane tiling of the kernels'
-(1, n_block) vector blocks. Winners are cached per (N, d, dtype) shape key;
-``report()`` exposes the cache so benchmarks can print the chosen blocks.
-
-Winners also persist to a result directory (``REPRO_AUTOTUNE_CACHE_DIR``,
-default ``<repo>/.autotune_cache``; empty string disables) as one small
-JSON per shape key, so measured picks survive process restarts — and CI
-restores the directory across workflow runs with ``actions/cache`` instead
-of re-measuring every run. Corrupt or unreadable entries are ignored and
-re-tuned.
+use (bytes actually moved per fused round) and rejects candidates whose
+per-step VMEM model exceeds the budget. Every candidate is a multiple of
+128 rows, the lane tiling of the kernels' (1, n_block) vector blocks.
+A pick is a pure function of (N, d, dtype, variant); winners are cached in
+memory per key, and ``report()`` exposes the cache so benchmarks can print
+the chosen blocks.
 """
 from __future__ import annotations
 
 import dataclasses
-import json
-import os
-import time
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 import jax.numpy as jnp
-
-from repro.common.device import REPO_ROOT
 
 N_BLOCK_CANDIDATES = (128, 256, 512, 1024)
 R_BLOCK_CANDIDATES = (8, 32, 64, 128, 256, 512)
 
-# The v5e compiler's default scoped VMEM. ``tile_vmem_bytes`` is fitted to
-# what compiles: against this budget every config it admits compiled for a
-# described v5e (f32 and bf16, d 64..8192, n_block 128..2048, R 1..512),
-# and it rejects a few that would also have compiled.
+# The v5e compiler's default scoped VMEM. Every (n_block, r_block) pair
+# ``tile_vmem_bytes`` admits against it compiles for a described v5e, for
+# both round variants, f32 and bf16 pools and d from 32 to 8192
+# (tests/test_tpu_compile.py sweeps them).
 VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 
 
@@ -57,9 +45,7 @@ VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 class BlockChoice:
     n_block: int
     r_block: int
-    hbm_bytes: float          # modeled bytes per fused round at (n, r)
-    wall_s: float             # measured s/round (0.0 when model-only)
-    source: str               # "model" | "measured"
+    hbm_bytes: float          # modeled bytes per fused round at (n, 1)
 
 
 # keyed (n, d, dtype name, round variant): the plain fused round and the
@@ -72,74 +58,6 @@ _CACHE: Dict[Tuple[int, int, str, str], BlockChoice] = {}
 VARIANTS = ("round", "gated")
 
 
-def cache_dir() -> Optional[str]:
-    """Result directory for persisted winners; None when disabled."""
-    d = os.environ.get("REPRO_AUTOTUNE_CACHE_DIR")
-    if d == "":
-        return None
-    return d or str(REPO_ROOT / ".autotune_cache")
-
-
-def _disk_path(key: Tuple[int, int, str, str]) -> Optional[str]:
-    d = cache_dir()
-    if d is None:
-        return None
-    return os.path.join(d, f"n{key[0]}_d{key[1]}_{key[2]}_{key[3]}.json")
-
-
-# bump when the candidate sets, the HBM/VMEM model, or the entry schema
-# change: older persisted winners are then ignored and re-tuned instead of
-# being trusted across a code change that invalidated them.
-# format 2: the round variant joined the key AND the filename — format-1
-# entries predate the gated round and could alias both variants
-# format 3: lane-major kernels, 128-multiple n_block, the 16 MiB VMEM model
-_DISK_FORMAT = 3
-
-
-def _disk_load(key: Tuple[int, int, str, str]) -> Optional[BlockChoice]:
-    path = _disk_path(key)
-    if path is None or not os.path.exists(path):
-        return None
-    try:
-        with open(path) as f:
-            raw = json.load(f)
-        if raw.get("format") != _DISK_FORMAT:
-            return None
-        choice = BlockChoice(int(raw["n_block"]), int(raw["r_block"]),
-                             float(raw["hbm_bytes"]), float(raw["wall_s"]),
-                             str(raw["source"]))
-    except (OSError, ValueError, KeyError, TypeError, AttributeError):
-        return None     # corrupt entry: fall through and re-tune
-    # never serve blocks the CURRENT candidate lists / VMEM model would
-    # reject (a stale-but-well-formed entry from different code)
-    n, d = key[0], key[1]
-    dtype_bytes = float(jnp.dtype(key[2]).itemsize)
-    if choice.n_block not in N_BLOCK_CANDIDATES \
-            or choice.r_block not in R_BLOCK_CANDIDATES \
-            or not _feasible(n, d, dtype_bytes, choice.n_block,
-                             choice.r_block):
-        return None
-    return choice
-
-
-def _disk_store(key: Tuple[int, int, str, str],
-                choice: BlockChoice) -> None:
-    path = _disk_path(key)
-    if path is None:
-        return
-    try:
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        # write-then-rename so a killed run never leaves a torn entry for
-        # the next (possibly cached-in-CI) run to trip over
-        tmp = path + f".tmp.{os.getpid()}"
-        with open(tmp, "w") as f:
-            json.dump({"format": _DISK_FORMAT,
-                       **dataclasses.asdict(choice)}, f)
-        os.replace(tmp, path)
-    except OSError:
-        pass            # persistence is best-effort; the run still has _CACHE
-
-
 def _pad_to(x: int, m: int) -> int:
     return -(-x // m) * m
 
@@ -148,7 +66,8 @@ def round_hbm_bytes(n: int, d: int, dtype_bytes: float, n_block: int,
                     r_block: int) -> float:
     """Modeled HBM bytes of ONE fused greedy round (see kernel.py ledger):
     pool read + min-dist read/write + weight read + per-block center
-    re-fetch + (max, argmax) block partials."""
+    re-fetch + (max, argmax) block partials, each one 128-lane group of
+    f32/i32 per row block."""
     nb = min(n_block, n)
     nn = -(-n // nb)
     np_ = nn * nb
@@ -156,7 +75,7 @@ def round_hbm_bytes(n: int, d: int, dtype_bytes: float, n_block: int,
     pool = np_ * d * dtype_bytes
     vectors = 3 * 4 * np_                 # mind in, mind out, weights in
     centers = nn * rp * (d * 4 + 4)       # (Rp, d) tile + sel idx per block
-    partials = nn * 2 * 4
+    partials = nn * 2 * 128 * 4
     return pool + vectors + centers + partials
 
 
@@ -182,49 +101,11 @@ def _feasible(n: int, d: int, dtype_bytes: float, n_block: int,
         <= VMEM_BUDGET_BYTES
 
 
-def _measure_round(x, n_block: int, variant: str = "round",
-                   reps: int = 3) -> float:
-    from repro.kernels.pairwise import kernel as _k
-    n = x.shape[0]
-    mind = jnp.full((n,), 3.4e38, jnp.float32)
-    c = x[:1]
-    if variant == "gated":
-        # measure the gated round at full occupancy (every block live):
-        # the worst case it must win at, and the shape-compatible one
-        nn = -(-n // min(n_block, n))
-        live = jnp.ones((nn,), jnp.int32)
-        pend = jnp.zeros((nn,), jnp.int32)
-
-        def run(m):
-            return _k.gated_greedy_round_pallas(x, m, c, live, pend,
-                                                n_block=n_block)
-    else:
-        sel = jnp.full((1,), -1, jnp.int32)
-
-        def run(m):
-            return _k.greedy_round_pallas(x, m, c, sel, n_block=n_block)
-
-    nm, _, _ = run(mind)
-    nm.block_until_ready()                # compile outside the timed region
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        nm, _, _ = run(nm)
-    nm.block_until_ready()
-    return (time.perf_counter() - t0) / reps
-
-
 def autotune_blocks(n: int, d: int, dtype=jnp.float32,
-                    measure: bool = False,
                     variant: str = "round") -> BlockChoice:
-    """Best (n_block, r_block) for an (N, d) pool of ``dtype``; cached
-    per round ``variant`` ("round" = plain fused, "gated" =
-    block-masked).
-
-    ``measure=True`` re-ranks the feasible n_block shortlist by wall clock
-    on the default device; it runs kernels, so call it outside any trace.
-    The served path never measures: the unlabeled pool's N changes with
-    every label, and each new N would re-time every candidate inside a
-    query."""
+    """Best (n_block, r_block) for an (N, d) pool of ``dtype`` under the
+    HBM and VMEM models; cached per round ``variant`` ("round" = plain
+    fused, "gated" = block-masked)."""
     if variant not in VARIANTS:
         raise ValueError(f"variant must be one of {VARIANTS}, "
                          f"got {variant!r}")
@@ -233,15 +114,6 @@ def autotune_blocks(n: int, d: int, dtype=jnp.float32,
     if key in _CACHE:
         return _CACHE[key]
     dtype_bytes = float(dt.itemsize)
-    # a persisted winner is reused when it is at least as informed as what
-    # this process would produce: measured entries always, model-only
-    # entries only for a model-only run (a TPU run re-measures and
-    # overwrites a stale model pick rather than trusting it)
-    disk = _disk_load(key)
-    if disk is not None and (disk.source == "measured" or not measure):
-        _CACHE[key] = disk
-        return disk
-
     # n_block is scored on the single-center round (R = 1, the greedy-loop
     # hot path); ties in modeled bytes break to the LARGER block (fewer
     # grid steps and partials to reduce host-side).
@@ -259,29 +131,9 @@ def autotune_blocks(n: int, d: int, dtype=jnp.float32,
     best_rb = min(r_cands,
                   key=lambda rb: (round_hbm_bytes(n, d, dtype_bytes, best_nb,
                                                   rb) / rb, -rb))
-    wall = 0.0
-    source = "model"
-    if measure:
-        # re-rank the model's feasible n_block shortlist by wall clock
-        import numpy as np
-        x = jnp.asarray(np.random.default_rng(0).normal(size=(n, d)), dtype)
-        timed = {nb: _measure_round(x, nb, variant) for nb in n_cands}
-        best_nb = min(timed, key=timed.get)
-        wall = timed[best_nb]
-        source = "measured"
-        # r_block feasibility depends on n_block: re-derive it at the
-        # measured winner or the cached pair can blow the VMEM budget
-        r_cands = [rb for rb in R_BLOCK_CANDIDATES
-                   if _feasible(n, d, dtype_bytes, best_nb, rb)] or \
-            [R_BLOCK_CANDIDATES[0]]
-        best_rb = min(r_cands,
-                      key=lambda rb: (round_hbm_bytes(n, d, dtype_bytes,
-                                                      best_nb, rb) / rb, -rb))
     choice = BlockChoice(best_nb, best_rb,
-                         round_hbm_bytes(n, d, dtype_bytes, best_nb, 1),
-                         wall, source)
+                         round_hbm_bytes(n, d, dtype_bytes, best_nb, 1))
     _CACHE[key] = choice
-    _disk_store(key, choice)
     return choice
 
 
@@ -292,6 +144,5 @@ def report() -> Dict[Tuple[int, int, str, str], BlockChoice]:
 
 
 def clear_cache() -> None:
-    """Clear the in-memory cache only; persisted winners stay on disk (the
-    next autotune_blocks reloads them, exactly like a fresh process)."""
+    """Forget every cached pick."""
     _CACHE.clear()

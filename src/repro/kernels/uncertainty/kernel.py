@@ -78,6 +78,8 @@ def uncertainty_stats_pallas(logits, *, row_block: int = 256,
                              v_block: int = 2048, interpret: bool = False):
     """logits: (N, V) -> (4, N) fp32 rows = [lc, mc, rc, es]."""
     N, V = logits.shape
+    if N == 0:                  # an empty shard: no rows, no grid
+        return jnp.zeros((4, 0), jnp.float32)
     rb = min(row_block, N)
     vb = min(v_block, V)
     nr = -(-N // rb)

@@ -128,10 +128,7 @@ class ALClient:
                  session: Optional[str] = None,
                  retries: int = 2,
                  retry_jitter_s: float = 0.05,
-                 op_timeout_s: Optional[float] = None,
-                 timeout_s: float = 60.0):
-        """``timeout_s`` bounds each TCP round trip on the socket; a cold
-        server compiling its programs may need more than the default."""
+                 op_timeout_s: Optional[float] = None):
         assert (local is None) != (url is None), "pass local= xor url="
         self._local = local
         self._rpc = None
@@ -147,8 +144,7 @@ class ALClient:
         self._jitter = random.Random(0xA1AA5)
         if url:
             host, port = url.rsplit(":", 1)
-            self._rpc = transport.RPCClient(host, int(port),
-                                            timeout=timeout_s)
+            self._rpc = transport.RPCClient(host, int(port))
         if session == "new":
             session = self.open_session()
         self._session = session

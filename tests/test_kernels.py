@@ -297,12 +297,11 @@ def test_greedy_round_tiebreak_stable_across_n_block(nblock):
 
 
 # ------------------------------------------------------------- autotuner ----
-def test_autotune_blocks_cached_and_feasible(monkeypatch):
+def test_autotune_blocks_cached_and_feasible():
     from repro.kernels.pairwise import autotune
 
-    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE_DIR", "")    # hermetic: no disk
     autotune.clear_cache()
-    ch = autotune.autotune_blocks(4096, 64, jnp.float32, measure=False)
+    ch = autotune.autotune_blocks(4096, 64, jnp.float32)
     assert ch.n_block in autotune.N_BLOCK_CANDIDATES
     assert ch.r_block in autotune.R_BLOCK_CANDIDATES
     assert autotune.tile_vmem_bytes(64, 4, ch.n_block, ch.r_block) \
@@ -312,7 +311,7 @@ def test_autotune_blocks_cached_and_feasible(monkeypatch):
     # the gated (block-masked) round is a SEPARATE cache entry: its winner
     # must never alias the plain round's (the PR-6 collision bug)
     ch_gated = autotune.autotune_blocks(4096, 64, jnp.float32,
-                                        measure=False, variant="gated")
+                                        variant="gated")
     assert (4096, 64, "float32", "gated") in autotune.report()
     assert autotune.autotune_blocks(
         4096, 64, jnp.float32, variant="gated") is ch_gated
@@ -320,47 +319,24 @@ def test_autotune_blocks_cached_and_feasible(monkeypatch):
     with pytest.raises(ValueError, match="variant"):
         autotune.autotune_blocks(4096, 64, jnp.float32, variant="bogus")
     # a huge feature dim must force smaller tiles, not blow the budget
-    ch_wide = autotune.autotune_blocks(4096, 8192, jnp.float32, measure=False)
+    ch_wide = autotune.autotune_blocks(4096, 8192, jnp.float32)
     assert autotune.tile_vmem_bytes(8192, 4, ch_wide.n_block,
                                     ch_wide.r_block) \
         <= autotune.VMEM_BUDGET_BYTES
     assert ch_wide.n_block <= ch.n_block
 
 
-def test_autotune_disk_cache_roundtrip(tmp_path, monkeypatch):
-    """Winners persist to the result directory (one JSON per shape key) and
-    reload across processes/cache clears; a corrupt entry re-tunes instead
-    of crashing; disabling via empty env writes nothing."""
+def test_autotune_pick_is_pure():
+    """A pick depends on (N, d, dtype) alone: clearing the cache and
+    re-picking gives an equal choice, and no file is written."""
     from repro.kernels.pairwise import autotune
 
-    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE_DIR", str(tmp_path))
     autotune.clear_cache()
-    ch = autotune.autotune_blocks(2048, 32, jnp.float32, measure=False)
-    entry = tmp_path / "n2048_d32_float32_round.json"
-    assert entry.exists()
-    autotune.clear_cache()                       # simulate a fresh process
-    assert autotune.autotune_blocks(2048, 32, jnp.float32,
-                                    measure=False) == ch
-    entry.write_text("not json")                 # corrupt: re-tune, rewrite
+    ch = autotune.autotune_blocks(2048, 32, jnp.float32)
     autotune.clear_cache()
-    assert autotune.autotune_blocks(2048, 32, jnp.float32,
-                                    measure=False) == ch
-    assert entry.read_text() != "not json"
-    # variants persist to DISTINCT files; a pre-variant (format-1) entry
-    # under the old aliasing name is never read
-    autotune.autotune_blocks(2048, 32, jnp.float32, measure=False,
-                             variant="gated")
-    assert (tmp_path / "n2048_d32_float32_gated.json").exists()
-    legacy = tmp_path / "n512_d8_float32.json"
-    legacy.write_text('{"format": 1, "n_block": 64, "r_block": 8, '
-                      '"hbm_bytes": 0.0, "wall_s": 0.0, "source": "model"}')
-    autotune.clear_cache()
-    autotune.autotune_blocks(512, 8, jnp.float32, measure=False)
-    assert (tmp_path / "n512_d8_float32_round.json").exists()
-    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE_DIR", "")
-    autotune.clear_cache()
-    autotune.autotune_blocks(1024, 16, jnp.float32, measure=False)
-    assert not (tmp_path / "n1024_d16_float32_round.json").exists()
+    assert (2048, 32, "float32", "round") not in autotune.report()
+    again = autotune.autotune_blocks(2048, 32, jnp.float32)
+    assert again == ch and again is not ch
 
 
 def test_autotune_model_amortizes_r_block():

@@ -213,3 +213,40 @@ def test_sharded_tiny_cache_recomputes_evicted_embeddings():
     assert len(set(res["keys"])) == 6
     srv.label(keys[:20], Y[:20])
     assert 0.0 <= srv.train_and_eval() <= 1.0
+
+
+PROBS_STRATEGIES = sorted(s for s in ZOO if "probs" in ZOO[s].needs)
+
+
+@pytest.fixture
+def uncertainty_interpret(monkeypatch):
+    """Every probs score runs the uncertainty kernel in interpret mode, as
+    ``impl="auto"`` runs the compiled kernel on the TPU. Traces cached
+    under the old dispatch are dropped on the way in and out."""
+    from repro.kernels.uncertainty import ops as unc_ops
+    monkeypatch.setattr(unc_ops, "_resolve", lambda impl: "interpret")
+    jax.clear_caches()
+    yield
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("strategy", PROBS_STRATEGIES)
+def test_kernel_scores_with_empty_shard(strategy, uncertainty_interpret):
+    """A shard with no unlabeled rows hands the uncertainty kernel a (0, C)
+    probs block; the kernel path must score it as empty and the selection
+    must match replicas=1."""
+    X, Y = image_pool(24, seed=3)
+    srvs = {r: _mlp_server(r) for r in (1, 3)}
+    keys = {r: s.push_data(list(X)) for r, s in srvs.items()}
+    # label every row of shard 0 plus a few others, so shard 0 is empty
+    lab = [i for i, k in enumerate(keys[3]) if replica_of(k, 3) == 0]
+    lab += [i for i in range(len(X)) if i not in lab][:3]
+    assert 0 < len(lab) < len(X)
+    for r, s in srvs.items():
+        s.label([keys[r][i] for i in lab], Y[lab])
+        s.train_and_eval()
+    res = {r: s.query(budget=4, strategy=strategy, rng_seed=2)
+           for r, s in srvs.items()}
+    assert len(res[1]["keys"]) == 4
+    assert res[3]["keys"] == res[1]["keys"]

@@ -5,7 +5,10 @@ compiler accepts their block layouts and VMEM use. These tests compile
 each kernel for a described (not attached) v5e at the chip smoke's widths
 — a 10,000-row pool of 512-wide ResNet-18 features with 10 classes, and
 the flash kernel at ``configs/text_al.yml``'s encoder shapes — and assert
-the compiled program holds the Mosaic kernel (``tpu_custom_call``).
+the compiled program holds the Mosaic kernel (``tpu_custom_call``). A
+sweep compiles both greedy rounds at every (n_block, r_block) pair the
+autotuner's VMEM model admits, over a range of feature widths and both
+pool dtypes, so no pick the model can make is refused on the chip.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -92,3 +95,34 @@ def test_flash_attention_compiles(one_chip):
              ((B, S, cfg.n_heads, cfg.hd), jnp.float32),
              ((B, S, cfg.n_kv_heads, cfg.hd), jnp.float32),
              ((B, S, cfg.n_kv_heads, cfg.hd), jnp.float32))
+
+
+SWEEP_N = 4096                   # four row blocks at the largest n_block
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("d", [32, 512, 2048, 8192])
+@pytest.mark.parametrize("variant", autotune.VARIANTS)
+def test_autotune_candidates_compile(one_chip, variant, d, dtype):
+    """Every candidate pair the VMEM model admits compiles."""
+    nbytes = jnp.dtype(dtype).itemsize
+    pairs = [(nb, rb) for nb in autotune.N_BLOCK_CANDIDATES
+             for rb in autotune.R_BLOCK_CANDIDATES
+             if autotune._feasible(SWEEP_N, d, nbytes, nb, rb)]
+    assert pairs, "the model must admit at least one pair"
+    for nb, rb in pairs:
+        if variant == "round":
+            _compile(one_chip,
+                     lambda x, m, c, s: greedy_round_pallas(x, m, c, s,
+                                                            n_block=nb),
+                     ((SWEEP_N, d), dtype), ((SWEEP_N,), jnp.float32),
+                     ((rb, d), jnp.float32), ((rb,), jnp.int32))
+        else:
+            nn = -(-SWEEP_N // nb)
+            _compile(one_chip,
+                     lambda live, pend, x, m, c: gated_greedy_round_pallas(
+                         x, m, c, live, pend, n_block=nb),
+                     ((nn,), jnp.int32), ((nn,), jnp.int32),
+                     ((SWEEP_N, d), dtype), ((SWEEP_N,), jnp.float32),
+                     ((rb, d), jnp.float32))
