@@ -53,6 +53,8 @@ import numpy as np
 from jax.experimental.shard_map import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.common import telemetry
+
 
 def shard_map(f, **kw):
     """shard_map with the static-replication check disabled (outputs are
@@ -408,11 +410,18 @@ class ShardColumns:
 
 def replica_map(fn: Callable, items: Sequence, executor=None) -> list:
     """Apply ``fn`` to every item — across the shard thread pool when one
-    is given (per-shard scoring runs in parallel), serially otherwise."""
+    is given (per-shard scoring runs in parallel, under the caller's span),
+    serially otherwise."""
     items = list(items)
     if executor is None or len(items) <= 1:
         return [fn(it) for it in items]
-    return list(executor.map(fn, items))
+    ctx = telemetry.context()
+
+    def run(it):
+        with telemetry.attached(ctx):
+            return fn(it)
+
+    return list(executor.map(run, items))
 
 
 def replica_total(shards: Sequence[ShardView]) -> int:
@@ -504,6 +513,7 @@ def _merge_proposals(props):
     return best
 
 
+@telemetry.traced("select.greedy")
 def replica_greedy_select(shards: Sequence[ShardView],
                           emb_list: Sequence[jax.Array], budget: int, *,
                           mind_list: Sequence[Optional[jax.Array]],
@@ -528,6 +538,10 @@ def replica_greedy_select(shards: Sequence[ShardView],
     in slot order — the standing-query replay engine (service layer) stores
     them so a later emit over a grown pool can prove "no new row beats any
     recorded winner" by streaming only the delta rows.
+
+    Each shard's proposal reads two device scalars (score and row) back to
+    the host; the loop counts them in ``select.d2h_syncs``, its picks in
+    ``select.picks`` and its per-pick winner masks in ``h2d_bytes``.
     """
     from repro.kernels.pairwise import ops
     nsh = len(shards)
@@ -541,7 +555,11 @@ def replica_greedy_select(shards: Sequence[ShardView],
         li = int(jnp.argmax(sc))
         return (float(sc[li]), int(s.gidx[li]), i, li)
 
+    def n_live(props):
+        return sum(p is not None for p in props)
+
     props = replica_map(propose, range(nsh), executor)
+    syncs, mask_bytes = 2 * n_live(props), 0
     for slot in range(start, budget):
         v, g, win_shard, win_local = _merge_proposals(props)
         if capture is not None:
@@ -560,9 +578,15 @@ def replica_greedy_select(shards: Sequence[ShardView],
                 emb_list[i], mind[i], center[None, :], mask,
                 weights=weight_for_slot(slot + 1, i), impl=impl)
             mind[i] = nm
-            return (float(lv), int(s.gidx[int(li)]), i, int(li))
+            li = int(li)
+            return (float(lv), int(s.gidx[li]), i, li)
 
         props = replica_map(fold, range(nsh), executor)
+        syncs += 2 * n_live(props)
+        mask_bytes += 4 * n_live(props)
+    telemetry.count("select.picks", budget - start)
+    telemetry.count("select.d2h_syncs", syncs)
+    telemetry.count("h2d_bytes", mask_bytes)
     return sel
 
 
@@ -597,7 +621,8 @@ class KCenterState:
             if s.n == 0:
                 out.append(None)
                 continue
-            out.append(jnp.asarray(self.minds[i][np.asarray(s.pool_rows)]))
+            out.append(telemetry.h2d(
+                self.minds[i][np.asarray(s.pool_rows)]))
         return out
 
     def pool_mind(self, i: int) -> np.ndarray:
@@ -668,13 +693,14 @@ class KCenterStateCache:
         vectors where the stamps allow and folding only the row/center
         deltas. ``centers[k]`` must be the feats row at ``locs[k]``."""
         from repro.kernels.pairwise import ops
+        h2d = telemetry.h2d
         locs = tuple(tuple(p) for p in locs)
         k = len(locs)
         if k == 0:
             return None
         centers = np.asarray(centers, np.float32)
         nsh = len(feats_l)
-        with self._lock:
+        with telemetry.span("select.warm_state"), self._lock:
             if head_version != self._head_version:
                 self._drop_all()
                 self._head_version = head_version
@@ -697,8 +723,7 @@ class KCenterStateCache:
                 if m is None:
                     if rows:
                         m = np.asarray(ops.warm_start_min_dist(
-                            jnp.asarray(feats), jnp.asarray(centers)),
-                            np.float32)
+                            h2d(feats), h2d(centers)), np.float32)
                     else:
                         m = np.zeros((0,), np.float32)
                     self.counters["rebuilds"] += 1
@@ -709,15 +734,13 @@ class KCenterStateCache:
                         # center delta: fold only the new centers over the
                         # cached rows; elementwise min == one joint fold
                         nm = np.asarray(ops.warm_start_min_dist(
-                            jnp.asarray(feats[:rc]),
-                            jnp.asarray(new_centers)), np.float32)
+                            h2d(feats[:rc]), h2d(new_centers)), np.float32)
                         m = np.minimum(m[:rc], nm)
                         self.counters["center_extends"] += 1
                     if rows > rc:
                         # row delta: fold ALL centers over just the new rows
                         ext = np.asarray(ops.warm_start_min_dist(
-                            jnp.asarray(feats[rc:rows]),
-                            jnp.asarray(centers)), np.float32)
+                            h2d(feats[rc:rows]), h2d(centers)), np.float32)
                         m = np.concatenate([m[:rc], ext])
                         self.counters["extends"] += 1
                         self.counters["rows_extended"] += rows - rc

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import telemetry
 from repro.core.strategies.base import Strategy, unit_weights
 from repro.core.strategies.uncertainty import lc_scores
 
@@ -195,7 +196,7 @@ def sharded_k_center(rng, budget: int, shards, *, init_centers=None,
             slack=prefilter.slack, executor=executor, impl=impl,
             state=state if warm else None)
     N = selection.replica_total(shards)
-    emb_list = [jnp.asarray(s.feats, jnp.float32) for s in shards]
+    emb_list = [telemetry.h2d(s.feats, jnp.float32) for s in shards]
     sel = np.zeros((budget,), np.int64)
     if weights_list is None:
         def weight_for_slot(slot, i):
@@ -209,7 +210,7 @@ def sharded_k_center(rng, budget: int, shards, *, init_centers=None,
             mind = state.view_minds(shards)
             capture = state.capture
         else:
-            init = jnp.asarray(init_centers, jnp.float32)
+            init = telemetry.h2d(init_centers, jnp.float32)
             mind = [ops.warm_start_min_dist(emb_list[i], init, impl=impl)
                     if s.n else None for i, s in enumerate(shards)]
         start = 0

@@ -113,8 +113,9 @@ def pairwise_sq_dists(x, c):
 
 @jax.jit
 def _sq_dist_to_center(x, center):
-    diff = x.astype(jnp.float32) - center.astype(jnp.float32)[None, :]
-    return jnp.sum(diff * diff, axis=-1)
+    with jax.named_scope("alaas.sq_dist_to_center"):
+        diff = x.astype(jnp.float32) - center.astype(jnp.float32)[None, :]
+        return jnp.sum(diff * diff, axis=-1)
 
 
 def sq_dist_to_center(x, center):
@@ -126,13 +127,16 @@ def sq_dist_to_center(x, center):
 @functools.partial(jax.jit, static_argnames=("impl", "n_block"))
 def _greedy_round(x, mind, centers, sel_idx, weights, impl: str,
                   n_block: int):
-    if impl == "auto":
-        impl = "pallas" if on_tpu() else "ref"
-    if impl == "ref":
-        return ref.greedy_round_ref(x, mind, centers, sel_idx, weights)
-    return greedy_round_pallas(x, mind, centers, sel_idx, weights,
-                               n_block=n_block,
-                               interpret=(impl == "interpret"))
+    # the scope names the kernel's op in a device trace
+    # (``%alaas._greedy_round.1 = ... custom-call(...)``)
+    with jax.named_scope("alaas._greedy_round"):
+        if impl == "auto":
+            impl = "pallas" if on_tpu() else "ref"
+        if impl == "ref":
+            return ref.greedy_round_ref(x, mind, centers, sel_idx, weights)
+        return greedy_round_pallas(x, mind, centers, sel_idx, weights,
+                                   n_block=n_block,
+                                   interpret=(impl == "interpret"))
 
 
 def autotuned_blocks(n: int, d: int, dtype=jnp.float32):

@@ -69,22 +69,29 @@ class FeatureBackend:
             return nll + 1e-4 * jnp.sum(p["w"] ** 2)
 
         @jax.jit
-        def step(p, _):
-            g = jax.grad(loss_fn)(p)
-            return jax.tree.map(lambda a, b: a - lr * b, p, g), None
+        def fit_head_step(p, _):
+            with jax.named_scope("alaas.fit_head_step"):
+                g = jax.grad(loss_fn)(p)
+                return jax.tree.map(lambda a, b: a - lr * b, p, g), None
 
         p = {"w": head.w, "b": head.b}
-        p, _ = jax.lax.scan(step, p, None, length=steps)
+        p, _ = jax.lax.scan(fit_head_step, p, None, length=steps)
         return HeadState(w=p["w"], b=p["b"])
 
     def probs(self, feats: np.ndarray, head: HeadState) -> np.ndarray:
-        logits = jnp.asarray(feats, jnp.float32) @ head.w + head.b
-        return np.asarray(jax.nn.softmax(logits, axis=-1))
+        return np.asarray(_head_probs(jnp.asarray(feats, jnp.float32),
+                                      head.w, head.b))
 
     def evaluate(self, feats: np.ndarray, labels: np.ndarray,
                  head: HeadState) -> float:
         p = self.probs(feats, head)
         return float(np.mean(p.argmax(-1) == np.asarray(labels)))
+
+
+@jax.jit
+def _head_probs(x, w, b):
+    with jax.named_scope("alaas.probs"):
+        return jax.nn.softmax(x @ w + b, axis=-1)
 
 
 class ResNetBackend(FeatureBackend):
@@ -100,8 +107,11 @@ class ResNetBackend(FeatureBackend):
         self.params = resnet_lib.init_resnet(self.cfg, rng)
         # params are arguments, not closed-over constants: baked in, the
         # ResNet-18 weights double the compiled program and its cache entry
-        self._feat = jax.jit(
-            lambda p, x: resnet_lib.resnet_features(p, self.cfg, x))
+        def resnet_features(p, x):
+            with jax.named_scope("alaas.resnet_features"):
+                return resnet_lib.resnet_features(p, self.cfg, x)
+
+        self._feat = jax.jit(resnet_features)
 
     def preprocess(self, raw: np.ndarray) -> np.ndarray:
         x = np.asarray(raw, np.float32)

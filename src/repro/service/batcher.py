@@ -14,6 +14,8 @@ from typing import Any, Callable, List, Sequence
 
 import numpy as np
 
+from repro.common import telemetry
+
 
 def bucket_size(n: int, max_batch: int) -> int:
     b = 1
@@ -39,15 +41,16 @@ class DynamicBatcher:
         self._pending: List = []
         self._lock = threading.Condition()
         self._stop = False
-        self.batches = 0
-        self.items = 0
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
 
     def submit(self, item: np.ndarray) -> "cf.Future":
         fut: cf.Future = cf.Future()
+        # the submitter's span context rides with the item: the batch's
+        # spans on the batcher thread name its first item's request
+        ctx = telemetry.context()
         with self._lock:
-            self._pending.append((item, fut))
+            self._pending.append((item, fut, ctx))
             self._lock.notify()
         return fut
 
@@ -64,11 +67,14 @@ class DynamicBatcher:
                     return
                 if not self._pending:
                     continue
-                deadline = time.perf_counter() + self.timeout_s
-                while (len(self._pending) < self.max_batch
-                       and time.perf_counter() < deadline):
-                    self._lock.wait(timeout=max(
-                        deadline - time.perf_counter(), 0.0))
+                ctx = self._pending[0][2]
+                with telemetry.attached(ctx), \
+                        telemetry.span("embed.batch_wait"):
+                    deadline = time.perf_counter() + self.timeout_s
+                    while (len(self._pending) < self.max_batch
+                           and time.perf_counter() < deadline):
+                        self._lock.wait(timeout=max(
+                            deadline - time.perf_counter(), 0.0))
                 batch = self._pending[: self.max_batch]
                 self._pending = self._pending[self.max_batch:]
             items = [b[0] for b in batch]
@@ -78,21 +84,19 @@ class DynamicBatcher:
                 n, self.max_batch)
             stacked = np.stack(items + [np.zeros_like(items[0])] * (b - n))
             try:
-                results = self.batch_fn(stacked, n)
+                with telemetry.attached(ctx):
+                    telemetry.count("embed.batches")
+                    telemetry.count("embed.rows_valid", n)
+                    telemetry.count("embed.rows_computed", b)
+                    results = self.batch_fn(stacked, n)
                 for f, r in zip(futs, results):
                     f.set_result(r)
             except BaseException as e:
                 for f in futs:
                     f.set_exception(e)
-            self.batches += 1
-            self.items += n
 
     def close(self):
         with self._lock:
             self._stop = True
             self._lock.notify_all()
         self._thread.join(timeout=5)
-
-    def stats(self) -> dict:
-        return {"batches": self.batches, "items": self.items,
-                "mean_batch": self.items / max(self.batches, 1)}

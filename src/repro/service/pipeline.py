@@ -14,6 +14,8 @@ import threading
 import time
 from typing import Any, Callable, Iterable, List, Optional, Sequence
 
+from repro.common import telemetry
+
 _SENTINEL = object()
 
 
@@ -71,7 +73,14 @@ class StagePipeline:
                     continue
             return _SENTINEL
 
+        # the caller's span context: the stages' spans name its request
+        ctx = telemetry.context()
+
         def worker(stage: Stage, qin: queue.Queue, qout: queue.Queue):
+            with telemetry.attached(ctx):
+                work(stage, qin, qout)
+
+        def work(stage: Stage, qin: queue.Queue, qout: queue.Queue):
             while True:
                 t0 = time.perf_counter()
                 item = get(qin)

@@ -59,6 +59,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from repro.common import telemetry
 from repro.core.agent.controller import run_pshea
 from repro.distributed.worker import (PhaseFailureInjector, ShardWorkerPool)
 from repro.core.prefilter import PrefilterConfig, maintain_summary
@@ -192,7 +193,6 @@ class ALSession:
         self._eval_set: Optional[tuple] = None
         self._oracle: Optional[Callable[[Sequence[str]], Sequence[int]]] = None
         self._lock = threading.RLock()
-        self.last_pipeline_stats = None
         # -- incremental pool-artifact engine ---------------------------
         # One ShardColumns per replica shard (ONE shard at replicas=1 —
         # the unsharded query path is just its 1-shard case). Columns are
@@ -287,20 +287,25 @@ class ALSession:
         ``keys`` are immediately known (content hashes)."""
         if asynchronous:
             return self._push_async(items)
+        return self._push_sync(items, pipelined)
+
+    @telemetry.traced("push")
+    def _push_sync(self, items: Sequence[np.ndarray],
+                   pipelined: bool) -> List[str]:
         self.flush()     # sync pushes order AFTER every pending async push
         # sync embedding stays on ONE pipeline even at replicas>1 (per-
         # shard parallel embedding is the ingest queue's job). The feature
         # path itself is batch-insensitive — row-local forward + one
         # canonical batch shape (DynamicBatcher pad_to_max) — so any
         # chunking of the same rows lands the identical feature bytes
-        keys = [content_key(np.asarray(it)) for it in items]
+        with telemetry.span("push.hash"):
+            keys = [content_key(np.asarray(it)) for it in items]
         todo = [(k, it) for k, it in zip(keys, items)
                 if k not in self.server.cache]
         with self._lock:
             self._append_rows(keys, [np.asarray(it) for it in items])
         if todo:
-            self.last_pipeline_stats = self.server._process(
-                todo, pipelined=pipelined)
+            self.server._process(todo, pipelined=pipelined)
         return keys
 
     def _append_rows(self, keys: Sequence[str],
@@ -328,7 +333,8 @@ class ALSession:
     # ----------------------------------------------------- async ingest --
     def _push_async(self, items: Sequence[np.ndarray]) -> PushTicket:
         items = [np.asarray(it) for it in items]
-        keys = [content_key(it) for it in items]
+        with telemetry.span("push.hash"):
+            keys = [content_key(it) for it in items]
         rows = len(items)
         nbytes = sum(int(it.nbytes) for it in items)
         cfg = self.server.config
@@ -365,7 +371,10 @@ class ALSession:
                                        self._ingest_rows)
             self._ingest_bytes_hw = max(self._ingest_bytes_hw,
                                         self._ingest_bytes)
-            self._ingest_queue.append((keys, items, fut))
+            # the pushing request's span context rides with the entry, so
+            # the worker's spans name the request that queued the rows
+            self._ingest_queue.append((keys, items, fut,
+                                       telemetry.context()))
             self._ingest_depth_hw = max(self._ingest_depth_hw,
                                         len(self._ingest_queue))
             if self._ingest_thread is None:
@@ -419,8 +428,9 @@ class ALSession:
             t_drain = time.monotonic()
             err: Optional[BaseException] = None
             try:
-                self._integrate(batch)
-                for keys, _, fut in batch:
+                with telemetry.attached(batch[0][3]):
+                    self._integrate(batch)
+                for keys, _, fut, _ in batch:
                     fut.set_result(keys)
             except BaseException as batch_err:
                 if len(batch) == 1:
@@ -432,9 +442,10 @@ class ALSession:
                     # cannot drop the rows of valid pushes drained in the
                     # same batch
                     for entry in batch:
-                        keys, _, fut = entry
+                        keys, _, fut, ctx = entry
                         try:
-                            self._integrate([entry])
+                            with telemetry.attached(ctx):
+                                self._integrate([entry])
                             fut.set_result(keys)
                         except BaseException as one_err:
                             err = one_err
@@ -452,10 +463,10 @@ class ALSession:
                 # bytes from the cap and wake any blocked producer
                 self._ingest_rows = max(
                     self._ingest_rows
-                    - sum(len(keys) for keys, _, _ in batch), 0)
+                    - sum(len(keys) for keys, _, _, _ in batch), 0)
                 self._ingest_bytes = max(
                     self._ingest_bytes
-                    - sum(int(it.nbytes) for _, items, _ in batch
+                    - sum(int(it.nbytes) for _, items, _, _ in batch
                           for it in items), 0)
                 dt = time.monotonic() - t_drain
                 self._ingest_drain_ema_s += 0.2 * (dt
@@ -464,25 +475,26 @@ class ALSession:
                     self._ingest_error = err
                 self._ingest_cv.notify_all()
 
+    @telemetry.traced("ingest.integrate")
     def _integrate(self, batch: List[tuple]) -> None:
         """Embed + append ONE drained ingest batch: the un-cached items of
         every queued push are grouped by replica shard and embedded in
         parallel; pool_version bumps once for the whole batch."""
         todo, seen = [], set()
-        for keys, items, _ in batch:
+        for keys, items, _, _ in batch:
             for k, it in zip(keys, items):
                 if k in seen or k in self.server.cache:
                     continue
                 seen.add(k)
                 todo.append((k, it))
         if todo:
-            self.last_pipeline_stats = self.server._process_replicated(todo)
+            self.server._process_replicated(todo)
         with self._lock:
             # ONE _append_rows call for the whole drained batch: one
             # pool_version bump, one rows_epoch tick per touched shard
             self._append_rows(
-                [k for keys, _, _ in batch for k in keys],
-                [it for _, items, _ in batch for it in items])
+                [k for keys, _, _, _ in batch for k in keys],
+                [it for _, items, _, _ in batch for it in items])
 
     def flush(self, timeout: Optional[float] = None) -> None:
         """Ingest barrier: returns once every previously queued async push
@@ -664,7 +676,7 @@ class ALSession:
                 # their rows), computed from cached feats — zero embeds
                 old = col.probs
                 newp = (np.asarray(backend.probs(
-                    col.feats[:col.feats_rows], head))
+                    telemetry.h2d(col.feats[:col.feats_rows]), head))
                     if col.feats_rows else None)
                 if newp is not None and col.spill is not None:
                     newp = col.spill.adopt(newp)
@@ -675,8 +687,8 @@ class ALSession:
                 col.probs_head_epoch = head_v
                 kind = kind or "probs"
             elif col.probs_rows < col.feats_rows:
-                newp = np.asarray(backend.probs(
-                    col.feats[col.probs_rows:col.feats_rows], head))
+                newp = np.asarray(backend.probs(telemetry.h2d(
+                    col.feats[col.probs_rows:col.feats_rows]), head))
                 col.probs, col.probs_rows = grow_append(
                     col.probs, col.probs_rows, newp, col.spill)
             if self._prefilter_cfg is not None:
@@ -724,7 +736,8 @@ class ALSession:
             return (f, p, r, i, [None] * self.replicas,
                     [-1] * self.replicas, [0] * self.replicas)
         with self._artifact_lock:
-            self._refresh_artifacts()
+            with telemetry.span("artifact.refresh"):
+                self._refresh_artifacts()
             feats_l = [c.feats_view(backend.feat_dim) for c in self._columns]
             probs_l = [c.probs_view(backend.num_classes)
                        for c in self._columns]
@@ -764,6 +777,7 @@ class ALSession:
         return ([p[0] for p in parts], [p[1] for p in parts],
                 [len(ks) for ks in shard_keys], index)
 
+    @telemetry.traced("retrain")
     def train_and_eval(self) -> float:
         self.flush()     # linearize after pending async ingests
         keys = list(self._labeled_keys)
@@ -772,18 +786,20 @@ class ALSession:
         backend = self.server.backend
         feats = self._feats_for(keys)
         labels = np.asarray([self._labels[k] for k in keys])
-        with self._lock:
+        with self._lock, telemetry.span("retrain.fit"):
             self._head = backend.fit_head(feats, labels, head=None)
             self.head_version += 1
         # the spec's invalidation matrix: a retrain drops the persisted
         # min-dist vectors on every shard (feats columns are untouched, so
         # the NEXT warm query re-folds but re-embeds nothing)
         self._kstate.invalidate()
-        if self._eval_set is None:  # no eval set: train-set accuracy proxy
-            return backend.evaluate(feats, labels, self._head)
-        return backend.evaluate(*self._eval_set, self._head)
+        with telemetry.span("retrain.eval"):
+            if self._eval_set is None:  # train-set accuracy proxy
+                return backend.evaluate(feats, labels, self._head)
+            return backend.evaluate(*self._eval_set, self._head)
 
     # ------------------------------------------------------------- query --
+    @telemetry.traced("query")
     def query(self, budget: int, strategy: Optional[str] = None,
               target_accuracy: Optional[float] = None, rng_seed: int = 0,
               pshea_workers: Optional[int] = None) -> dict:
@@ -823,21 +839,22 @@ class ALSession:
         if budget == 0:    # fully-labeled pool: strategies need >= 1 row
             return {"keys": [], "indices": [], "strategy": strategy,
                     "cache": self.server.cache.stats()}
-        rows = np.asarray([index[k][1] for k in unlabeled], np.int64)
-        feats = feats_all[rows]
-        probs = probs_all[rows]
-        labeled_emb = None
-        if self._labeled_keys:
-            lab_rows = [index[k][1] for k in self._labeled_keys
-                        if k in index and index[k][1] < n_rows]
-            if lab_rows:
-                labeled_emb = feats_all[np.asarray(lab_rows, np.int64)]
-        import jax.numpy as jnp
+        with telemetry.span("select.gather"):
+            rows = np.asarray([index[k][1] for k in unlabeled], np.int64)
+            feats = feats_all[rows]
+            probs = probs_all[rows]
+            labeled_emb = None
+            if self._labeled_keys:
+                lab_rows = [index[k][1] for k in self._labeled_keys
+                            if k in index and index[k][1] < n_rows]
+                if lab_rows:
+                    labeled_emb = feats_all[np.asarray(lab_rows, np.int64)]
+        h2d = telemetry.h2d
         idx = strat.select(
             jax.random.PRNGKey(rng_seed), budget,
-            probs=jnp.asarray(probs) if "probs" in strat.needs else None,
-            embeddings=jnp.asarray(feats) if "embeddings" in strat.needs else None,
-            labeled_embeddings=(jnp.asarray(labeled_emb)
+            probs=h2d(probs) if "probs" in strat.needs else None,
+            embeddings=h2d(feats) if "embeddings" in strat.needs else None,
+            labeled_embeddings=(h2d(labeled_emb)
                                 if labeled_emb is not None else None))
         idx = np.asarray(idx)
         return {"keys": [unlabeled[i] for i in idx],
@@ -872,41 +889,41 @@ class ALSession:
         if budget == 0:
             return {"keys": [], "indices": [], "strategy": strategy,
                     "cache": self.server.cache.stats()}
-        rows: List[List[int]] = [[] for _ in range(self.replicas)]
-        gpos: List[List[int]] = [[] for _ in range(self.replicas)]
-        for g, k in enumerate(unlabeled):
-            si, li = index[k]
-            rows[si].append(li)
-            gpos[si].append(g)
-        pf_cfg = self._prefilter_cfg
-        shards = []
-        for si in range(self.replicas):
-            r = np.asarray(rows[si], np.int64)
-            summ = summaries[si]
-            # a summary older than the pinned view is fine (its tail is
-            # scanned in full); one COVERING MORE rows than the view — a
-            # racing refresh that rebuilt past our pin — is not usable
-            if summ is not None and summ.covered > rows_l[si]:
-                summ = None
-            shards.append(ShardView(
-                feats=feats_l[si][r] if r.size else feats_l[si][:0],
-                probs=probs_l[si][r] if r.size else probs_l[si][:0],
-                gidx=np.asarray(gpos[si], np.int64),
-                summary=summ if pf_cfg is not None else None,
-                # pool-level context: the prefilter engines and the
-                # persisted-state gather both address rows by their
-                # shard-local pool position (cheap views, always set)
-                pool_rows=r,
-                pool_feats=feats_l[si],
-                probs_epoch=epochs[si]))
-        labeled_emb = None
-        lab: List[Tuple[int, int]] = []
-        if self._labeled_keys:
-            lab = [index[k] for k in self._labeled_keys if covered(k)]
-            if lab:
-                import jax.numpy as jnp
-                labeled_emb = jnp.asarray(
-                    np.stack([feats_l[si][li] for si, li in lab]))
+        with telemetry.span("select.gather"):
+            rows: List[List[int]] = [[] for _ in range(self.replicas)]
+            gpos: List[List[int]] = [[] for _ in range(self.replicas)]
+            for g, k in enumerate(unlabeled):
+                si, li = index[k]
+                rows[si].append(li)
+                gpos[si].append(g)
+            pf_cfg = self._prefilter_cfg
+            shards = []
+            for si in range(self.replicas):
+                r = np.asarray(rows[si], np.int64)
+                summ = summaries[si]
+                # a summary older than the pinned view is fine (its tail is
+                # scanned in full); one COVERING MORE rows than the view — a
+                # racing refresh that rebuilt past our pin — is not usable
+                if summ is not None and summ.covered > rows_l[si]:
+                    summ = None
+                shards.append(ShardView(
+                    feats=feats_l[si][r] if r.size else feats_l[si][:0],
+                    probs=probs_l[si][r] if r.size else probs_l[si][:0],
+                    gidx=np.asarray(gpos[si], np.int64),
+                    summary=summ if pf_cfg is not None else None,
+                    # pool-level context: the prefilter engines and the
+                    # persisted-state gather both address rows by their
+                    # shard-local pool position (cheap views, always set)
+                    pool_rows=r,
+                    pool_feats=feats_l[si],
+                    probs_epoch=epochs[si]))
+            labeled_emb = None
+            lab: List[Tuple[int, int]] = []
+            if self._labeled_keys:
+                lab = [index[k] for k in self._labeled_keys if covered(k)]
+                if lab:
+                    labeled_emb = telemetry.h2d(
+                        np.stack([feats_l[si][li] for si, li in lab]))
         state = None
         if self._use_kstate(strategy) and labeled_emb is not None:
             state = self._kstate.prepare(
@@ -1256,8 +1273,7 @@ class ALSession:
                 "strategy_state": {
                     "enabled": self.server.config.strategy_state_cache,
                     **self._kstate.stats()},
-                "standing_queries": self._standing_stats(),
-                "pipeline": self.last_pipeline_stats}
+                "standing_queries": self._standing_stats()}
 
     def _standing_stats(self) -> dict:
         with self._standing_lock:
@@ -1398,16 +1414,19 @@ class ALServer:
 
         batcher = DynamicBatcher(infer_batch, max_batch=bs, pad_to_max=True)
 
+        @telemetry.traced("embed.fetch")
         def fetch(chunk_items):
             if self.fetch_latency_s:
                 time.sleep(self.fetch_latency_s)
             return [(k, self.fetch_fn(v)) for k, v in chunk_items]
 
+        @telemetry.traced("embed.preprocess")
         def preprocess(chunk_items):
             ks = [k for k, _ in chunk_items]
             raw = np.stack([np.asarray(v) for _, v in chunk_items])
             return ks, self.backend.preprocess(raw)
 
+        @telemetry.traced("embed.infer")
         def infer(args):
             ks, batch = args
             feats = batcher.score(list(batch))
@@ -1424,7 +1443,6 @@ class ALServer:
                     self.cache.put(k, np.asarray(f))
         finally:
             batcher.close()
-        return pipe.stats()
 
     def _embed_chunk(self, raw: np.ndarray, bs: int, *,
                      backend: FeatureBackend) -> np.ndarray:
@@ -1438,7 +1456,8 @@ class ALServer:
         return np.asarray(backend.features(x))[:n]
 
     def _infer_batch(self, stacked: np.ndarray, n_valid: int):
-        feats = self.backend.features(stacked)
+        with telemetry.span("embed.forward"):
+            feats = self.backend.features(stacked)
         self.count_embeds(n_valid)
         return [feats[i] for i in range(n_valid)]
 
@@ -1459,17 +1478,8 @@ class ALServer:
         # group's pipeline retries — cache puts are content-addressed and
         # idempotent, and the rows append only after every group lands, so
         # a recovered kill loses nothing
-        executor = self.shard_scoped("ingest")
-        per_group = list(executor.map(
-            lambda g: self._process(g, pipelined=True), groups))
-        # keep the single-pipeline stats shape (one dict per stage): sum
-        # each stage's counters across the parallel per-shard pipelines
-        merged = [dict(stage) for stage in per_group[0]]
-        for stats in per_group[1:]:
-            for agg, stage in zip(merged, stats):
-                for field in ("items", "busy_s", "wait_s"):
-                    agg[field] += stage[field]
-        return merged
+        replica_map(lambda g: self._process(g, pipelined=True), groups,
+                    self.shard_scoped("ingest"))
 
     def _auto_candidates(self) -> List[str]:
         """The PSHEA agent's strategy registry: the paper's 7, plus the
@@ -1528,10 +1538,6 @@ class ALServer:
                       session: Optional[str] = None) -> dict:
         return self.session(session).standing_poll(query_id, since)
 
-    @property
-    def last_pipeline_stats(self):
-        return self.session().last_pipeline_stats
-
     def stats(self, session: Optional[str] = None) -> dict:
         s = self.session(session).stats()
         s["cache"] = self.cache.stats()
@@ -1545,4 +1551,6 @@ class ALServer:
         # absent/in-process -> a disabled placeholder, same shape)
         ts = self._transport_stats
         s["admission"] = (ts() if ts is not None else {"enabled": False})
+        # the in-program spans and counters, process-wide
+        s["trace"] = telemetry.snapshot()
         return s

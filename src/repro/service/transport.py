@@ -49,6 +49,7 @@ from typing import Any, Callable, Dict, Optional
 import msgpack
 import numpy as np
 
+from repro.common import telemetry
 from repro.service.admission import (AdmissionConfig, FrameScheduler,
                                      attach_stream)
 from repro.service.errors import DeadlineExceeded, ServerOverloaded
@@ -414,10 +415,13 @@ class RPCServer:
                               "error": "deadline expired at queue head"})
             return
         try:
-            fn = self.handlers[msg.get("op")]
-            result = fn(msg.get("payload") or {}, msg.get("session"),
-                        conn.ctx)
-            self._send(conn, {"ok": True, "id": rid, "result": result})
+            with telemetry.span(
+                    "rpc", op=str(msg.get("op")),
+                    request_id=f"{msg.get('session') or 'default'}/{rid}"):
+                fn = self.handlers[msg.get("op")]
+                result = fn(msg.get("payload") or {}, msg.get("session"),
+                            conn.ctx)
+                self._send(conn, {"ok": True, "id": rid, "result": result})
         except ServerOverloaded as e:
             self._send(conn, {"ok": False, "id": rid, "code": "overloaded",
                               "retry_after_s": e.retry_after_s,

@@ -5,6 +5,7 @@ import time
 import numpy as np
 import pytest
 
+from repro.common import telemetry
 from repro.data.synthetic import image_pool
 from repro.service.batcher import DynamicBatcher, bucket_size
 from repro.service.cache import EmbeddingCache, content_key
@@ -200,6 +201,7 @@ def test_batcher_timeout_flush():
     the batcher may not hold a partial batch waiting for a full one."""
     b = DynamicBatcher(lambda stacked, n: [stacked[i] for i in range(n)],
                        max_batch=64, timeout_s=0.02)
+    before = telemetry.snapshot()["counters"]
     try:
         t0 = time.perf_counter()
         futs = [b.submit(np.full(4, i, np.float32)) for i in range(3)]
@@ -208,8 +210,11 @@ def test_batcher_timeout_flush():
         for i, o in enumerate(outs):
             np.testing.assert_array_equal(o, np.full(4, i, np.float32))
         assert dt < 1.0, f"timeout flush took {dt:.3f}s"
-        assert b.stats()["batches"] == 1      # one partial batch, one flush
-        assert b.stats()["items"] == 3
+        after = telemetry.snapshot()["counters"]
+        # one partial batch, one flush
+        assert after["embed.batches"] - before.get("embed.batches", 0) == 1
+        assert (after["embed.rows_valid"]
+                - before.get("embed.rows_valid", 0)) == 3
     finally:
         b.close()
 
@@ -482,6 +487,54 @@ def test_tcp_roundtrip(pool):
     finally:
         cli.close()
         rpc.stop()
+
+
+def test_tcp_query_spans_and_counters(pool):
+    """A coreset query over TCP leaves one span tree under its
+    ``alaas.rpc`` span, counts its picks, its host syncs (two scalar reads
+    per proposal: the warm start's and one per pick) and the bytes of
+    every upload, and ``stats()`` carries the recorder's snapshot."""
+    X = pool[0]
+    srv = _mlp_server()
+    rpc = serve_tcp(srv)
+    cli = ALClient(url=f"127.0.0.1:{rpc.port}", session="new")
+    n, n_lab, budget, d = 120, 12, 9, 32
+    try:
+        keys = cli.push_data(list(X[:n]))
+        cli.label(keys[:n_lab], [i % 10 for i in range(n_lab)])
+        cli.train_eval()
+        t0 = time.perf_counter()
+        out = cli.query(budget, "coreset", rng_seed=3)
+        st = cli.stats()
+    finally:
+        cli.close()
+        rpc.stop()
+    assert len(out["keys"]) == budget
+    (query,) = [e for e in telemetry.events()
+                if e.t1 >= t0 and e.name == "alaas.query"]
+    evs = [e for e in telemetry.events() if e.request_id == query.request_id]
+    parents = {e.name: e.parent for e in evs if e.value is None}
+    assert parents == {
+        "alaas.rpc": None, "alaas.query": "alaas.rpc",
+        "alaas.artifact.refresh": "alaas.query",
+        "alaas.select.gather": "alaas.query",
+        "alaas.select.warm_state": "alaas.query",
+        "alaas.select.greedy": "alaas.query"}
+
+    def counted(name):
+        return sum(e.value for e in evs if e.name == name)
+
+    assert counted("select.picks") == budget
+    assert counted("select.d2h_syncs") == 2 * (budget + 1)
+    assert counted("h2d_bytes") == 4 * (
+        n * d                   # probs refresh: the pool's feats
+        + n_lab * d             # the labeled embeddings
+        + n * d + n_lab * d     # warm state: pool feats and the centers
+        + (n - n_lab) * d       # the unlabeled view the loop reads
+        + (n - n_lab)           # its min-dists
+        + budget)               # each pick's winner mask
+    assert st["trace"]["spans"]["alaas.select.greedy"]["count"] >= 1
+    assert st["trace"]["counters"]["select.picks"] >= budget
 
 
 def test_pipelined_push_equals_serial_push(pool):
