@@ -513,13 +513,100 @@ def _merge_proposals(props):
     return best
 
 
+_NO_ROW = np.iinfo(np.int32).max
+
+
+def static_weights(weights_list: Sequence[jax.Array]):
+    """``weight_for_slot`` for weights fixed per shard (weighted k-center,
+    margin density): every slot ranks by the same per-shard vectors."""
+    return jax.tree_util.Partial(_same_weights, tuple(weights_list))
+
+
+def _same_weights(weights, slot, gidxs):
+    return weights
+
+
+def _merge_on_device(vals, locs, gidxs):
+    """``_merge_proposals`` in ``jnp`` over the shards' ``(score, local
+    row)`` proposals: the largest score, ties to the lowest global index.
+    Returns the winner's ``(shard position, global index, score, local
+    row)``."""
+    gids = jnp.stack([g[li] for g, li in zip(gidxs, locs)])
+    vals, locs = jnp.stack(vals), jnp.stack(locs)
+    k = jnp.argmin(jnp.where(vals == jnp.max(vals), gids, _NO_ROW))
+    return k, gids[k], vals[k], locs[k]
+
+
+@functools.partial(jax.jit, static_argnames=("blocks", "impl"))
+def _greedy_loop(embs, minds, gidxs, weight_for_slot, bounds, *, blocks,
+                 impl):
+    """Slots ``bounds[0] .. bounds[1]`` of the sharded greedy loop as one
+    device program. Returns ``(sel, scores)``, buffers of the pool's total
+    row count: slot ``j`` holds its winner's global index and merged score.
+    The bounds are traced, so one program serves every budget."""
+    from repro.kernels.pairwise import ops
+    from repro.kernels.pairwise.kernel import greedy_layout
+    live = [i for i, e in enumerate(embs) if e.shape[0]]
+    live_gidxs = [gidxs[i] for i in live]
+    ns = [embs[i].shape[0] for i in live]
+    nps = [greedy_layout(n, blocks[i])[1] for n, i in zip(ns, live)]
+    total = sum(g.shape[0] for g in gidxs)
+    start, stop = bounds[0], jnp.minimum(bounds[1], total)
+
+    def row(v, n, np_):      # (n,) -> the kernel's padded (1, Np) row
+        return jnp.pad(v.astype(jnp.float32), (0, np_ - n))[None, :]
+
+    def weights(slot):
+        if weight_for_slot is None:
+            return [None] * len(live)
+        ws = weight_for_slot(slot, gidxs)
+        return [ws[i] for i in live]
+
+    # the pool is padded to the kernel's layout once, not once per pick
+    xs = [jnp.pad(embs[i].astype(jnp.float32), ((0, np_ - n), (0, 0)))
+          for n, np_, i in zip(ns, nps, live)]
+    ms = [row(minds[i], n, np_) for n, np_, i in zip(ns, nps, live)]
+
+    # slot ``start``: each shard's masked argmax, the flat path's pre-loop
+    # proposal
+    vals, locs = [], []
+    for w, i in zip(weights(start), live):
+        sc = ops.masked_weighted_score(minds[i], w)
+        li = jnp.argmax(sc).astype(jnp.int32)
+        vals.append(sc[li])
+        locs.append(li)
+    k, g, v, l = _merge_on_device(vals, locs, live_gidxs)
+    sel = jnp.zeros((total,), jnp.int32).at[start].set(g)
+    scores = jnp.zeros((total,), jnp.float32).at[start].set(v)
+
+    def body(slot, carry):
+        ms, sel, scores, k, l = carry
+        # fold the previous winner (local row ``l`` of live shard ``k``)
+        center = jnp.stack([x[jnp.minimum(l, n - 1)]
+                            for x, n in zip(xs, ns)])[k][None, :]
+        vals, locs, out = [], [], []
+        for p, (w, i) in enumerate(zip(weights(slot), live)):
+            nm, li, lv = ops.greedy_round_padded(
+                xs[p], ms[p], center, jnp.where(k == p, l, -1)[None],
+                None if w is None else row(w, ns[p], nps[p]),
+                n=ns[p], n_block=blocks[i], impl=impl)
+            out.append(nm)
+            vals.append(lv)
+            locs.append(li)
+        k, g, v, l = _merge_on_device(vals, locs, live_gidxs)
+        return out, sel.at[slot].set(g), scores.at[slot].set(v), k, l
+
+    _, sel, scores, _, _ = jax.lax.fori_loop(
+        start + 1, stop, body, (ms, sel, scores, k, l))
+    return sel, scores
+
+
 @telemetry.traced("select.greedy")
 def replica_greedy_select(shards: Sequence[ShardView],
                           emb_list: Sequence[jax.Array], budget: int, *,
                           mind_list: Sequence[Optional[jax.Array]],
                           sel: np.ndarray, start: int,
-                          weight_for_slot: Callable[[int, int], Optional[jax.Array]],
-                          executor=None, impl: str = "auto",
+                          weight_for_slot=None, impl: str = "auto",
                           capture: Optional[list] = None) -> np.ndarray:
     """Local-propose / global-dedup greedy rounds over replica shards —
     ``distributed_k_center``'s round structure generalized to hash-sharded
@@ -528,65 +615,54 @@ def replica_greedy_select(shards: Sequence[ShardView],
 
     Per slot: every shard runs ONE fused ``greedy_round`` over its rows
     (min-dist fold + winner masking + local weighted argmax), proposes
-    ``(score, global index)``, and the coordinator merge picks the winner.
-    ``weight_for_slot(slot, shard)`` supplies the weights ranking the
-    candidate for ``slot``. Bit-identical to the single-pool greedy loop:
-    the per-row floats are slice-invariant and both tie-break layers reduce
-    to the lowest global index.
+    ``(score, global index)``, and the merge picks the winner: the largest
+    score, ties to the lowest global index. Bit-identical to the
+    single-pool greedy loop: the per-row floats are slice-invariant and
+    both tie-break layers reduce to the lowest global index.
+
+    Slots ``start .. budget`` run as one device program (``_greedy_loop``)
+    whose compile depends on the shards' shapes only, not on the budget;
+    the host reads the selection back once, at the end.
+    ``weight_for_slot`` is None (unweighted) or a ``jax.tree_util.Partial``
+    traced as ``weight_for_slot(slot, gidxs)`` into the shards' weight
+    vectors for ``slot``'s pick (``static_weights``; BADGE's Gumbel draws).
 
     ``capture`` (optional list) records the merged winner's score per slot
     in slot order — the standing-query replay engine (service layer) stores
     them so a later emit over a grown pool can prove "no new row beats any
     recorded winner" by streaming only the delta rows.
 
-    Each shard's proposal reads two device scalars (score and row) back to
-    the host; the loop counts them in ``select.d2h_syncs``, its picks in
-    ``select.picks`` and its per-pick winner masks in ``h2d_bytes``.
+    Counts its picks in ``select.picks``, its read-back in
+    ``select.d2h_syncs`` and its uploads (row indices, bounds) in
+    ``h2d_bytes``. The shards' arrays are put on the first one's device.
     """
     from repro.kernels.pairwise import ops
-    nsh = len(shards)
-    mind = list(mind_list)
+    stop = min(budget, replica_total(shards))
+    if stop <= start:
+        return sel
+    dev = next(iter(emb_list[0].devices()))
 
-    def propose(i):
-        s = shards[i]
-        if s.n == 0:
-            return None
-        sc = ops.masked_weighted_score(mind[i], weight_for_slot(start, i))
-        li = int(jnp.argmax(sc))
-        return (float(sc[li]), int(s.gidx[li]), i, li)
+    def put(x):
+        return jax.device_put(x, dev)
 
-    def n_live(props):
-        return sum(p is not None for p in props)
-
-    props = replica_map(propose, range(nsh), executor)
-    syncs, mask_bytes = 2 * n_live(props), 0
-    for slot in range(start, budget):
-        v, g, win_shard, win_local = _merge_proposals(props)
-        if capture is not None:
-            capture.append(float(v))
-        sel[slot] = g
-        center = emb_list[win_shard][win_local]
-
-        def fold(i, win_shard=win_shard, win_local=win_local,
-                 center=center, slot=slot):
-            s = shards[i]
-            if s.n == 0:
-                return None
-            mask = jnp.asarray(
-                [win_local if i == win_shard else -1], jnp.int32)
-            nm, li, lv = ops.greedy_round(
-                emb_list[i], mind[i], center[None, :], mask,
-                weights=weight_for_slot(slot + 1, i), impl=impl)
-            mind[i] = nm
-            li = int(li)
-            return (float(lv), int(s.gidx[li]), i, li)
-
-        props = replica_map(fold, range(nsh), executor)
-        syncs += 2 * n_live(props)
-        mask_bytes += 4 * n_live(props)
-    telemetry.count("select.picks", budget - start)
-    telemetry.count("select.d2h_syncs", syncs)
-    telemetry.count("h2d_bytes", mask_bytes)
+    embs = tuple(put(e) for e in emb_list)
+    minds = tuple(put(jnp.zeros((0,), jnp.float32) if m is None else m)
+                  for m in mind_list)
+    gidxs = tuple(put(telemetry.h2d(s.gidx, jnp.int32)) for s in shards)
+    bounds = put(telemetry.h2d(np.asarray([start, stop], np.int32)))
+    blocks = tuple(ops.autotuned_blocks(*e.shape, e.dtype).n_block
+                   if e.shape[0] else 0 for e in embs)
+    got, scores = jax.device_get(_greedy_loop(
+        embs, minds, gidxs, jax.tree.map(put, weight_for_slot), bounds,
+        blocks=blocks, impl=impl))
+    for e in embs:
+        if e.shape[0]:
+            ops.record_greedy_rounds(e, stop - start - 1)
+    sel[start:stop] = got[start:stop]
+    if capture is not None:
+        capture.extend(float(v) for v in scores[start:stop])
+    telemetry.count("select.picks", stop - start)
+    telemetry.count("select.d2h_syncs", 1)
     return sel
 
 

@@ -198,12 +198,6 @@ def sharded_k_center(rng, budget: int, shards, *, init_centers=None,
     N = selection.replica_total(shards)
     emb_list = [telemetry.h2d(s.feats, jnp.float32) for s in shards]
     sel = np.zeros((budget,), np.int64)
-    if weights_list is None:
-        def weight_for_slot(slot, i):
-            return None
-    else:
-        def weight_for_slot(slot, i):
-            return weights_list[i]
     capture = None
     if warm:
         if state is not None:
@@ -223,8 +217,9 @@ def sharded_k_center(rng, budget: int, shards, *, init_centers=None,
         start = 1
     return selection.replica_greedy_select(
         shards, emb_list, budget, mind_list=mind, sel=sel, start=start,
-        weight_for_slot=weight_for_slot, executor=executor, impl=impl,
-        capture=capture)
+        weight_for_slot=(None if weights_list is None
+                         else selection.static_weights(weights_list)),
+        impl=impl, capture=capture)
 
 
 def _kcg_sharded(rng, budget, shards, *, labeled_embeddings=None,

@@ -109,13 +109,11 @@ def _weighted_kcenter_select(rng, budget, *, probs, embeddings,
 
 
 # ------------------------------------------------- replica-sharded paths --
-def sharded_kmeans_pp(rng, x_list, shards, k: int, executor=None,
-                      impl: str = "auto"):
+def sharded_kmeans_pp(rng, x_list, shards, k: int, impl: str = "auto"):
     """Replica-sharded ``kmeans_pp_sample``: the per-slot Gumbel weights are
     drawn over the FULL (N,) pool from the same key schedule as the single
     path and sliced per shard by global position, so each D² draw is the
     identical categorical sample."""
-    import threading
     from repro.core import selection
     N = selection.replica_total(shards)
     keys = jax.random.split(rng, k + 1)
@@ -123,23 +121,21 @@ def sharded_kmeans_pp(rng, x_list, shards, k: int, executor=None,
     mind = selection.replica_seed_min_dist(shards, x_list, first)
     sel = np.zeros((k,), np.int64)
     sel[0] = first
-    gumbel = {}                        # slot -> full (N,) weight draw
-    gumbel_lock = threading.Lock()     # shards race on a slot's first use
-
-    def weight_for_slot(slot, i):
-        with gumbel_lock:
-            if slot not in gumbel:
-                # slots advance monotonically: older draws are dead
-                for old in [s for s in gumbel if s < slot]:
-                    del gumbel[old]
-                gumbel[slot] = jnp.exp(
-                    jax.random.gumbel(keys[slot], (N,), jnp.float32))
-            w = gumbel[slot]
-        return w[jnp.asarray(shards[i].gidx)]
-
+    # one key row per pool row: the loop's program depends on N, not on k
+    keys = jnp.zeros((max(N, k) + 1,) + keys.shape[1:],
+                     keys.dtype).at[:k + 1].set(keys)
     return selection.replica_greedy_select(
         shards, x_list, k, mind_list=mind, sel=sel, start=1,
-        weight_for_slot=weight_for_slot, executor=executor, impl=impl)
+        weight_for_slot=jax.tree_util.Partial(_gumbel_weights, keys),
+        impl=impl)
+
+
+def _gumbel_weights(keys, slot, gidxs):
+    """Slot ``slot``'s D² draw, ``exp(gumbel(keys[slot], (N,)))``, sliced
+    to each shard's rows."""
+    n = sum(g.shape[0] for g in gidxs)
+    w = jnp.exp(jax.random.gumbel(keys[slot], (n,), jnp.float32))
+    return tuple(w[g] for g in gidxs)
 
 
 def _badge_sharded(rng, budget, shards, *, labeled_embeddings=None,
@@ -154,7 +150,7 @@ def _badge_sharded(rng, budget, shards, *, labeled_embeddings=None,
                    .astype(jnp.float32)
                    * jnp.asarray(s.feats, jnp.float32)),
         shards, executor)
-    return sharded_kmeans_pp(rng, g_list, shards, budget, executor=executor)
+    return sharded_kmeans_pp(rng, g_list, shards, budget)
 
 
 def density_scores_sharded(rng, shards, executor=None, n_ref: int = 256):

@@ -193,8 +193,17 @@ def _greedy_kernel(x_ref, mind_ref, c_ref, sel_ref, w_ref,
                barg_ref)
 
 
+def greedy_layout(n: int, n_block: int):
+    """``(rows per block, padded rows)`` of the fused round over an
+    ``n``-row pool at ``n_block``: the layout a caller that pads once
+    hands to ``greedy_round_pallas(..., n=n)``."""
+    nb = min(n_block, n)
+    return nb, -(-n // nb) * nb
+
+
 def greedy_round_pallas(x, mind, centers, sel_idx, weights=None, *,
-                        n_block: int = 256, interpret: bool = False):
+                        n_block: int = 256, interpret: bool = False,
+                        n: int | None = None):
     """One fused greedy round: fold ``centers`` into the running min-dist,
     mask ``sel_idx``, and return the next (weighted) farthest point.
 
@@ -209,24 +218,37 @@ def greedy_round_pallas(x, mind, centers, sel_idx, weights=None, *,
     the first max in the block, the host reduction the first max block).
 
     Returns ``(new_mind (N,) f32, next_idx () i32, next_score () f32)``.
+
+    With ``n`` the operands come padded already, at ``greedy_layout(n,
+    n_block)``: x (Np, d), mind and weights (1, Np) rows, rows ``n:``
+    ignored. Nothing is padded or sliced, and ``new_mind`` stays a (1, Np)
+    row: a loop folding many rounds over one pool pads it once.
     """
-    N, d = x.shape
     R = centers.shape[0]
     if sel_idx.shape[0] != R:
         raise ValueError(
             f"sel_idx must mask exactly the queued centers: got "
             f"{sel_idx.shape[0]} indices for {R} centers")
-    nb = min(n_block, N)
-    nn = -(-N // nb)
-    Np = nn * nb
+    padded = n is not None
+    N, d = (n, x.shape[1]) if padded else x.shape
+    nb, Np = greedy_layout(N, n_block)
+    nn = Np // nb
     Rp = -(-R // 8) * 8
-    if Np != N:
-        x = jnp.pad(x, ((0, Np - N), (0, 0)))
+    if padded:
+        if x.shape[0] != Np or mind.shape != (1, Np):
+            raise ValueError(
+                f"padded operands must hold {Np} rows for n={N} at "
+                f"n_block={n_block}: got {x.shape[0]} and {mind.shape}")
+        w = jnp.ones((1, Np), jnp.float32) if weights is None else weights
+    else:
+        if Np != N:
+            x = jnp.pad(x, ((0, Np - N), (0, 0)))
+        mind = _row_vec(mind, Np - N)
+        w = (jnp.ones((1, Np), jnp.float32) if weights is None
+             else _row_vec(weights, Np - N))
     if Rp != R:
         centers = jnp.pad(centers, ((0, Rp - R), (0, 0)))
         sel_idx = jnp.pad(sel_idx, (0, Rp - R), constant_values=-1)
-    w = (jnp.ones((1, Np), jnp.float32) if weights is None
-         else _row_vec(weights, Np - N))
     nmind, bmax, barg = pl.pallas_call(
         functools.partial(_greedy_kernel, n=N, r=R, n_block=nb),
         grid=(nn,),
@@ -250,11 +272,11 @@ def greedy_round_pallas(x, mind, centers, sel_idx, weights=None, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(x, _row_vec(mind, Np - N), centers.astype(jnp.float32),
-      sel_idx.astype(jnp.int32).reshape(Rp, 1), w)
+    )(x, mind.astype(jnp.float32), centers.astype(jnp.float32),
+      sel_idx.astype(jnp.int32).reshape(Rp, 1), w.astype(jnp.float32))
     # O(N / N_b) reduction over block partials picks the next center.
     nxt, score = _partials(bmax, barg)
-    return nmind[0, :N], nxt, score
+    return (nmind if padded else nmind[0, :N]), nxt, score
 
 
 def _gated_kernel(live_ref, pend_ref, x_ref, mind_ref, c_ref, w_ref,

@@ -18,7 +18,8 @@ import numpy as np
 
 from repro.common.device import on_tpu
 from repro.kernels.pairwise import autotune, ref
-from repro.kernels.pairwise.kernel import (BIG, greedy_round_pallas,
+from repro.kernels.pairwise.kernel import (BIG, greedy_layout,
+                                           greedy_round_pallas,
                                            pairwise_min_argmin_pallas)
 
 
@@ -73,6 +74,12 @@ def record_pool_rows(n: int) -> None:
     cluster scans)."""
     if _TRACKING[0]:
         _STATS["pool_rows"] += int(n)
+
+
+def record_greedy_rounds(x, rounds: int) -> None:
+    """Account ``rounds`` fused rounds over the pool ``x`` that a device
+    loop ran (its trace calls ``greedy_round_padded`` once)."""
+    _record(x, emb_reads=rounds, vec_streams=2 * rounds)
 
 
 # ------------------------------------------------- pairwise reductions ----
@@ -170,6 +177,27 @@ def greedy_round(x, mind, centers, sel_idx, weights=None, impl: str = "auto",
                                            x.dtype).n_block
     _record(x, emb_reads=1, vec_streams=2)
     return _greedy_round(x, mind, centers, sel_idx, weights, impl, n_block)
+
+
+def greedy_round_padded(xp, mind, centers, sel_idx, weights, *, n: int,
+                        n_block: int, impl: str = "auto"):
+    """``greedy_round`` on operands padded once at ``greedy_layout(n,
+    n_block)`` — xp (Np, d), mind and weights (1, Np) rows — for loops
+    that trace many rounds over one pool. Returns ``(new_mind (1, Np),
+    next_idx, next_score)``; rows ``n:`` never win and their min-dist is
+    not read. Call it inside a trace: it is not jitted and records no op
+    accounting."""
+    with jax.named_scope("alaas._greedy_round"):
+        if impl == "auto":
+            impl = "pallas" if on_tpu() else "ref"
+        if impl != "ref":
+            return greedy_round_pallas(xp, mind, centers, sel_idx, weights,
+                                       n_block=n_block, n=n,
+                                       interpret=(impl == "interpret"))
+        nm, nxt, score = ref.greedy_round_ref(
+            xp[:n], mind[0, :n], centers, sel_idx,
+            None if weights is None else weights[0, :n])
+        return jnp.pad(nm, (0, xp.shape[0] - n))[None, :], nxt, score
 
 
 @jax.jit

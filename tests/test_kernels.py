@@ -135,6 +135,40 @@ def test_greedy_round_weighted_argmax():
     np.testing.assert_allclose(nv_k, nv_r, rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["unweighted", "weighted"])
+def test_greedy_round_on_padded_operands(weighted):
+    """Operands padded once to the kernel's layout, handed over with their
+    real row count, give the unpadded call's (mind[:n], idx, score)
+    bitwise, at a ragged N (not a multiple of n_block)."""
+    from repro.kernels.pairwise.kernel import (greedy_layout,
+                                               greedy_round_pallas)
+
+    N, d, nb = 77, 48, 32
+    x = _arr((N, d), jnp.float32)
+    c = _arr((1, d), jnp.float32)
+    mind = jnp.asarray(np.abs(rng.normal(size=(N,))) * 10, jnp.float32)
+    mind = mind.at[5].set(-1.0)                 # a row picked earlier
+    sel = jnp.asarray([40], jnp.int32)
+    w = (jnp.asarray(np.abs(rng.normal(size=(N,))) + 0.1, jnp.float32)
+         if weighted else None)
+    _, Np = greedy_layout(N, nb)
+    assert Np % nb == 0 and Np > N
+
+    def row(v):
+        return jnp.pad(v, (0, Np - N))[None, :]
+
+    nm_p, ni_p, nv_p = greedy_round_pallas(
+        jnp.pad(x, ((0, Np - N), (0, 0))), row(mind), c, sel,
+        None if w is None else row(w), n_block=nb, n=N, interpret=True)
+    nm_u, ni_u, nv_u = greedy_round_pallas(x, mind, c, sel, w, n_block=nb,
+                                           interpret=True)
+    assert nm_p.shape == (1, Np)
+    np.testing.assert_array_equal(np.asarray(nm_p)[0, :N], np.asarray(nm_u))
+    assert int(ni_p) == int(ni_u) < N
+    assert np.asarray(nv_p).tobytes() == np.asarray(nv_u).tobytes()
+
+
 def test_greedy_round_no_mask_sentinel():
     """sel_idx = -1 must mask nothing."""
     from repro.kernels.pairwise import ref

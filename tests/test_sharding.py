@@ -138,6 +138,143 @@ def test_sharded_strategy_empty_shard_edge(strategy):
     assert sharded.tolist() == single.tolist()
 
 
+# ------------------------------------------------ the device pick loop --
+FAMILIES = ("seeded", "warm", "static", "gumbel")
+
+
+@pytest.fixture
+def captured(monkeypatch):
+    """Every ``replica_greedy_select`` call records its per-slot merged
+    scores into the returned list."""
+    from repro.core import selection
+    real, got = selection.replica_greedy_select, []
+
+    def recording(*args, **kwargs):
+        cap = []
+        kwargs["capture"] = cap
+        out = real(*args, **kwargs)
+        got.append(cap)
+        return out
+
+    monkeypatch.setattr(selection, "replica_greedy_select", recording)
+    return got
+
+
+def _flat_family(family, rng, budget, feats, labeled):
+    """The single-pool selection and the scores its loop ranks each pick
+    by, replayed with the flat path's own round."""
+    from repro.core.strategies.diversity import k_center_greedy
+    from repro.core.strategies.hybrid import kmeans_pp_sample
+    from repro.kernels.pairwise import ops
+    x = jnp.asarray(feats)
+    N = x.shape[0]
+    w = jnp.asarray(np.linspace(0.2, 1.0, N), jnp.float32)
+    init = jnp.asarray(labeled) if family in ("warm", "static") else None
+    if family == "gumbel":
+        sel = np.asarray(kmeans_pp_sample(rng, x, budget))
+        keys = jax.random.split(rng, budget + 1)
+
+        def weight(j):
+            return jnp.exp(jax.random.gumbel(keys[j], (N,), jnp.float32))
+    else:
+        sel = np.asarray(k_center_greedy(
+            rng, budget, x, init_centers=init,
+            weights=w if family == "static" else None))
+
+        def weight(j):
+            return w if family == "static" else None
+    if init is None:
+        mind = ops.sq_dist_to_center(x, x[sel[0]]).at[sel[0]].set(-1.0)
+        start = 1
+    else:
+        mind, start = ops.warm_start_min_dist(x, init), 0
+    scores = []
+    for j in range(start, budget):
+        scores.append(float(ops.masked_weighted_score(mind, weight(j))[sel[j]]))
+        mind = ops.greedy_round(x, mind, x[sel[j]][None, :],
+                                jnp.asarray([sel[j]], jnp.int32),
+                                weights=weight(j))[0]
+    return sel, scores, w
+
+
+def _sharded_family(family, rng, budget, shards, labeled, w):
+    from repro.core.strategies.diversity import sharded_k_center
+    from repro.core.strategies.hybrid import sharded_kmeans_pp
+    if family == "gumbel":
+        return sharded_kmeans_pp(
+            rng, [jnp.asarray(s.feats) for s in shards], shards, budget)
+    return sharded_k_center(
+        rng, budget, shards,
+        init_centers=(jnp.asarray(labeled)
+                      if family in ("warm", "static") else None),
+        weights_list=([w[jnp.asarray(s.gidx)] for s in shards]
+                      if family == "static" else None))
+
+
+@pytest.mark.parametrize("pool", ["ragged", "empty_shard"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_device_loop_matches_flat_path(family, pool, pool_artifacts,
+                                       captured):
+    """The device pick loop's selections and captured per-slot scores equal
+    the flat ``k_center_greedy`` / ``kmeans_pp_sample`` bitwise: unweighted
+    (seeded and warm), static-weight and Gumbel-weight rounds, at every
+    shard count, and with empty shards."""
+    feats, probs, labeled, keys = pool_artifacts
+    if pool == "empty_shard":
+        feats, probs, keys = feats[:5], probs[:5], keys[:5]
+    budget = 4 if pool == "empty_shard" else 9
+    rng = jax.random.PRNGKey(13)
+    sel, scores, w = _flat_family(family, rng, budget, feats, labeled)
+    for r in REPLICAS:
+        shards = _make_shards(feats, probs, keys, r)
+        if pool == "empty_shard" and r == 7:
+            assert any(s.n == 0 for s in shards)
+        del captured[:]
+        got = _sharded_family(family, rng, budget, shards, labeled, w)
+        assert np.asarray(got).tolist() == sel.tolist(), (family, r)
+        (cap,) = captured
+        assert np.asarray(cap, np.float32).tobytes() == \
+            np.asarray(scores, np.float32).tobytes(), (family, r)
+
+
+def _counted(name, fn):
+    from repro.common import telemetry
+    before = telemetry.snapshot()["counters"].get(name, 0)
+    fn()
+    return telemetry.snapshot()["counters"].get(name, 0) - before
+
+
+@pytest.fixture(scope="module")
+def wide_pool():
+    rng = np.random.default_rng(21)
+    feats = rng.standard_normal((150, 16)).astype(np.float32)
+    keys = [f"wide-{i}" for i in range(150)]
+    labeled = rng.standard_normal((5, 16)).astype(np.float32)
+    return _make_shards(feats, feats, keys, 3), jnp.asarray(labeled)
+
+
+def _warm_query(shards, labeled, budget):
+    from repro.core.strategies.diversity import sharded_k_center
+    return sharded_k_center(jax.random.PRNGKey(0), budget, shards,
+                            init_centers=labeled)
+
+
+def test_device_loop_syncs_do_not_grow_with_budget(wide_pool):
+    """One query reads its selection back once, whatever its budget."""
+    syncs = {b: _counted("select.d2h_syncs",
+                         lambda b=b: _warm_query(*wide_pool, b))
+             for b in (1, 64)}
+    assert syncs == {1: 1, 64: 1}
+
+
+def test_device_loop_compiles_once_per_pool_shape(wide_pool):
+    """After a budget-1 query, a budget-64 query over the same shards
+    compiles nothing: the budget is a traced bound of one program."""
+    _warm_query(*wide_pool, 1)
+    assert _counted("compile.count",
+                    lambda: _warm_query(*wide_pool, 64)) == 0
+
+
 def test_every_zoo_strategy_has_a_sharded_path():
     assert SHARDED_COMPLETE
     assert all(ZOO[s].sharded_fn is not None for s in ZOO)

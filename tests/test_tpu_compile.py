@@ -65,6 +65,26 @@ def test_greedy_round_compiles(one_chip, centers):
              ((r, D), jnp.float32), ((r,), jnp.int32))
 
 
+def test_device_pick_loop_compiles(one_chip):
+    """The sharded k-center pick loop as one device program at the
+    benchmark cell's widths: a 49,000 x 512 pool on one shard, unweighted,
+    with the budget a traced bound. The kernel keeps its trace name inside
+    the loop body."""
+    from repro.core.selection import _greedy_loop
+    rows = 49_000
+    blocks = (autotune.autotune_blocks(rows, D, jnp.float32).n_block,)
+    compiled = _compile(
+        one_chip,
+        lambda x, m, g, b: _greedy_loop((x,), (m,), (g,), None, b,
+                                        blocks=blocks, impl="pallas"),
+        ((rows, D), jnp.float32), ((rows,), jnp.float32),
+        ((rows,), jnp.int32), ((2,), jnp.int32))
+    text = compiled.as_text()
+    assert "while" in text
+    assert any("_greedy_round" in line and "custom-call(" in line
+               for line in text.splitlines())
+
+
 def test_gated_greedy_round_compiles(one_chip):
     nb = 256
     nn = -(-N // nb)
