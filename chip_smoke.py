@@ -14,8 +14,13 @@ lowered selection ops must hold the compiled Pallas kernels
 (``tpu_custom_call``).
 
 Four chips: a replicas=4 and a replicas=1 server get the same pushes,
-labels and queries. Their selections must be equal, and the replicas=4
-lanes' feature and round outputs must sit on four distinct chips.
+labels and queries. Their selections must be equal, the replicas=4
+lanes' feature outputs must sit on four distinct chips, and its coreset
+pick loop must be one program across the four: each lane's shard of the
+pool, min-dists and row indices on its lane's chip, the selection on
+every chip, ``select.loop_devices`` 4 (1 at replicas=1). Then a seeded
+49,000 x 512 pool's warm coreset selection (1,000 of it) over four shards
+on four chips must equal the one-shard, one-chip selection bit for bit.
 
 Every phase prints its wall time and XLA compile count (smoke output, not
 metrics). Any failure exits non-zero; without a TPU the script exits
@@ -28,7 +33,6 @@ import argparse
 import contextlib
 import json
 import sys
-import threading
 import time
 import traceback
 from pathlib import Path
@@ -251,13 +255,14 @@ def one_chip(log, seed):
 def four_chips(log, seed):
     import jax
     import numpy as np
+    from repro.common import telemetry
+    from repro.core import selection
     from repro.data.synthetic import image_pool
-    from repro.kernels.pairwise import ops
     from repro.service.config import ALServiceConfig
     x, y = image_pool(POOL_ROWS, hw=IMAGE_HW, seed=seed)
     lab = np.random.default_rng(seed).choice(POOL_ROWS, WARM_LABELS,
                                              replace=False)
-    feat_devs, round_devs = set(), {}
+    feat_devs, loops = set(), []
     with log.phase("serve replicas=4 and replicas=1 over tcp"):
         s4, rpc4, c4 = _serve(ALServiceConfig(
             model_name="resnet18", batch_size=BATCH, replicas=4))
@@ -271,26 +276,30 @@ def four_chips(log, seed):
         return out
 
     s4.backend._feat = feat_recorded
-    greedy_round = ops.greedy_round
+    lane_loop = selection._lane_loop
 
-    def round_recorded(*args, **kwargs):
-        out = greedy_round(*args, **kwargs)
-        name = threading.current_thread().name
-        if name.startswith("shard"):        # a replicas=4 lane thread
-            lane = int(name.split("-")[0][len("shard"):])
-            round_devs.setdefault(lane, set()).update(out[0].devices())
+    def loop_recorded(pool, mind, gidx, *args, **kwargs):
+        out = lane_loop(pool, mind, gidx, *args, **kwargs)
+        loops.append({
+            "operands": [[s.device for s in a.addressable_shards]
+                         for a in (pool, mind, gidx)],
+            "outputs": [r.sharding.device_set for r in out]})
         return out
 
-    ops.greedy_round = round_recorded
+    selection._lane_loop = loop_recorded
     try:
-        sel = {}
+        sel, spans = {}, {}
         for name, cli in (("replicas=4", c4), ("replicas=1", c1)):
             with log.phase(f"{name}: push, label, train, lc + coreset"):
                 keys = _push(cli, x, asynchronous=True)
                 cli.label([keys[i] for i in lab], y[lab].tolist())
                 cli.train_eval()
-                sel[name] = {s: cli.query(BUDGET, s)["keys"]
-                             for s in ("lc", "coreset")}
+                sel[name] = {"lc": cli.query(BUDGET, "lc")["keys"]}
+                c0 = telemetry.snapshot()["counters"].get(
+                    "select.loop_devices", 0)
+                sel[name]["coreset"] = cli.query(BUDGET, "coreset")["keys"]
+                spans[name] = telemetry.snapshot()["counters"].get(
+                    "select.loop_devices", 0) - c0
                 print(f"  workers={cli.stats()['workers']}", flush=True)
         with log.phase("compare replicas=4 with replicas=1"):
             for s in ("lc", "coreset"):
@@ -300,24 +309,76 @@ def four_chips(log, seed):
                     raise AssertionError(f"{s} selections differ")
             print(f"  feature outputs on: {sorted(str(d) for d in feat_devs)}",
                   flush=True)
-            for lane in sorted(round_devs):
-                print(f"  lane {lane} round outputs on: "
-                      f"{sorted(str(d) for d in round_devs[lane])}",
-                      flush=True)
-            lane_dev = [round_devs.get(i, set()) for i in range(4)]
             if len(feat_devs) != 4:
                 raise AssertionError("feature outputs are not on 4 chips")
-            if any(len(d) != 1 for d in lane_dev) or \
-                    len(set.union(*lane_dev)) != 4:
-                raise AssertionError("each lane's round outputs must sit "
-                                     "on its own chip")
-            if set.union(*lane_dev) != set(jax.devices()):
+            lanes = s4.shard_runtime().devices
+            print(f"  lane devices: {[str(d) for d in lanes]}", flush=True)
+            print(f"  coreset pick loop devices: {spans}", flush=True)
+            if spans != {"replicas=4": 4, "replicas=1": 1}:
+                raise AssertionError("the replicas=4 pick loop must span "
+                                     "four devices, replicas=1 one")
+            if len(loops) != 1:
+                raise AssertionError(f"{len(loops)} pick loops across "
+                                     f"devices, expected the coreset's")
+            for i, devs in enumerate(loops[0]["operands"]):
+                print(f"  pick loop operand {i} shards on: "
+                      f"{[str(d) for d in devs]}", flush=True)
+                if devs != lanes:
+                    raise AssertionError("each lane's shard must sit on "
+                                         "its own chip")
+            if set(lanes) != set(jax.devices()):
                 raise AssertionError("lanes do not cover the host's chips")
+            if any(d != set(lanes) for d in loops[0]["outputs"]):
+                raise AssertionError("the loop's selection is not on "
+                                     "every lane's chip")
     finally:
-        ops.greedy_round = greedy_round
+        selection._lane_loop = lane_loop
         for cli, rpc in ((c4, rpc4), (c1, rpc1)):
             cli.close()
             rpc.stop()
+
+
+def lanes_direct(log, seed, rows=49_000, d=512, labeled=1_000,
+                 budget=1_000):
+    """A seeded ``rows`` x ``d`` pool hash-sharded four ways, one shard
+    per chip, against the same pool as one shard on one chip: the warm
+    coreset selection and its per-pick scores must agree bit for bit.
+    Prints each loop's time once compiled."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.core.selection import ShardView, replica_of
+    from repro.core.strategies.diversity import sharded_k_center
+    rng = np.random.default_rng(seed)
+    feats = rng.standard_normal((rows, d)).astype(np.float32)
+    init = jnp.asarray(rng.standard_normal((labeled, d)).astype(np.float32))
+    keys = [f"row-{i}" for i in range(rows)]
+    devs = jax.devices()[:4]
+    parts = [np.asarray([i for i, k in enumerate(keys)
+                         if replica_of(k, 4) == s], np.int64)
+             for s in range(4)]
+    layouts = {
+        "1 shard, 1 chip": [ShardView(feats=feats, probs=None,
+                                      gidx=np.arange(rows))],
+        "4 shards, 4 chips": [ShardView(feats=feats[g], probs=None, gidx=g,
+                                        device=devs[s])
+                              for s, g in enumerate(parts)]}
+    got = {}
+    for name, shards in layouts.items():
+        with log.phase(f"warm coreset {budget} of {rows}x{d}: {name}"):
+            for _ in range(2):          # the second call runs compiled
+                t0 = time.perf_counter()
+                sel = np.asarray(sharded_k_center(
+                    jax.random.PRNGKey(seed), budget, shards,
+                    init_centers=init))
+                wall = time.perf_counter() - t0
+            got[name] = sel
+            print(f"  compiled query: {wall:.4f} s, shard rows "
+                  f"{[s.n for s in shards]}", flush=True)
+    same = np.array_equal(*got.values())
+    print(f"  4 chips == 1 chip: {same}", flush=True)
+    if not same:
+        raise AssertionError("the four-chip selection differs")
 
 
 def main() -> int:
@@ -350,7 +411,11 @@ def main() -> int:
           f"count={len(devices)} compile_cache={cache}", flush=True)
     log = PhaseLog(jax)
     try:
-        (four_chips if args.four_chips else one_chip)(log, args.seed)
+        if args.four_chips:
+            four_chips(log, args.seed)
+            lanes_direct(log, args.seed)
+        else:
+            one_chip(log, args.seed)
     except Exception:
         traceback.print_exc()
         print("chip_smoke: FAILED", file=sys.stderr)

@@ -36,6 +36,7 @@ from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 PREFIX = "alaas."
 RING_SIZE = 131072
@@ -160,10 +161,14 @@ def count(name: str, n: float = 1) -> None:
                       threading.current_thread().name, n))
 
 
-def h2d(x, dtype=None):
-    """``jnp.asarray(x, dtype)``, counting the bytes of a host array it
-    uploads in ``h2d_bytes``; a device array passes uncounted."""
-    out = jnp.asarray(x, dtype)
+def h2d(x, dtype=None, device=None):
+    """``jnp.asarray(x, dtype)``, or the host array ``x`` put on
+    ``device``, counting the bytes of a host array it uploads in
+    ``h2d_bytes``; a device array passes uncounted."""
+    if device is None:
+        out = jnp.asarray(x, dtype)
+    else:
+        out = jax.device_put(np.asarray(x, dtype), device)
     if not isinstance(x, jax.Array):
         count("h2d_bytes", out.nbytes)
     return out

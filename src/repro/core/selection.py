@@ -32,6 +32,10 @@ constructed to be bit-identical to the single-pool path:
     exactly ``jnp.argmax`` / ``jax.lax.top_k`` semantics on the
     concatenated vector.
 
+The greedy loop runs on the device: on one device, or, when every
+shard's lane has a device of its own, as one SPMD program across those
+devices whose per-pick merge is a collective (``_lane_loop``).
+
 ``ShardColumns`` + ``grow_append`` are the storage side of the same
 contract: each shard's (feats, probs) artifact columns live in growable
 append-only buffers with per-column epoch stamps, so a data change
@@ -51,7 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental.shard_map import shard_map as _shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.common import telemetry
 
@@ -217,6 +221,8 @@ class ShardView:
     pool_rows: Optional[np.ndarray] = None    # (n,) int64 shard-local rows
     pool_feats: Optional[np.ndarray] = None   # (rows, d) pinned feats view
     probs_epoch: int = -1
+    # the shard's lane device (``ShardWorkerPool.devices``); None = unpinned
+    device: Optional[Any] = None
 
     @property
     def n(self) -> int:
@@ -488,12 +494,15 @@ def replica_seed_min_dist(shards: Sequence[ShardView],
     (k-center greedy, BADGE's D² sampling)."""
     from repro.kernels.pairwise import ops
     fsi, fli = locate_row(shards, first)
+    center = emb_list[fsi][fli]
     mind = []
     for i, s in enumerate(shards):
         if s.n == 0:
             mind.append(None)
             continue
-        m = ops.sq_dist_to_center(emb_list[i], emb_list[fsi][fli])
+        # a shard on another device than the seed's gets its own copy
+        m = ops.sq_dist_to_center(emb_list[i], jax.device_put(
+            center, next(iter(emb_list[i].devices()))))
         if i == fsi:
             m = m.at[fli].set(-1.0)
         mind.append(m)
@@ -516,16 +525,6 @@ def _merge_proposals(props):
 _NO_ROW = np.iinfo(np.int32).max
 
 
-def static_weights(weights_list: Sequence[jax.Array]):
-    """``weight_for_slot`` for weights fixed per shard (weighted k-center,
-    margin density): every slot ranks by the same per-shard vectors."""
-    return jax.tree_util.Partial(_same_weights, tuple(weights_list))
-
-
-def _same_weights(weights, slot, gidxs):
-    return weights
-
-
 def _merge_on_device(vals, locs, gidxs):
     """``_merge_proposals`` in ``jnp`` over the shards' ``(score, local
     row)`` proposals: the largest score, ties to the lowest global index.
@@ -537,9 +536,20 @@ def _merge_on_device(vals, locs, gidxs):
     return k, gids[k], vals[k], locs[k]
 
 
+def lane_devices(shards: Sequence[ShardView]) -> Optional[tuple]:
+    """The shards' lane devices when the pick loop runs across them: more
+    than one shard, each pinned to a device of its own. None where shards
+    share a device or are unpinned (the single-device loop)."""
+    devs = tuple(s.device for s in shards)
+    if len(devs) < 2 or any(d is None for d in devs) \
+            or len(set(devs)) < len(devs):
+        return None
+    return devs
+
+
 @functools.partial(jax.jit, static_argnames=("blocks", "impl"))
-def _greedy_loop(embs, minds, gidxs, weight_for_slot, bounds, *, blocks,
-                 impl):
+def _greedy_loop(embs, minds, gidxs, weight_for_slot, bounds, statics=None,
+                 *, blocks, impl):
     """Slots ``bounds[0] .. bounds[1]`` of the sharded greedy loop as one
     device program. Returns ``(sel, scores)``, buffers of the pool's total
     row count: slot ``j`` holds its winner's global index and merged score.
@@ -557,9 +567,11 @@ def _greedy_loop(embs, minds, gidxs, weight_for_slot, bounds, *, blocks,
         return jnp.pad(v.astype(jnp.float32), (0, np_ - n))[None, :]
 
     def weights(slot):
+        if statics is not None:
+            return [statics[i] for i in live]
         if weight_for_slot is None:
             return [None] * len(live)
-        ws = weight_for_slot(slot, gidxs)
+        ws = weight_for_slot(slot, gidxs, total)
         return [ws[i] for i in live]
 
     # the pool is padded to the kernel's layout once, not once per pick
@@ -601,11 +613,179 @@ def _greedy_loop(embs, minds, gidxs, weight_for_slot, bounds, *, blocks,
     return sel, scores
 
 
-@telemetry.traced("select.greedy")
+@functools.partial(jax.jit, static_argnames=("mesh", "n", "n_block",
+                                             "total", "impl"))
+def _lane_loop(x, mind, gidx, statics, weight_for_slot, bounds, *, mesh, n,
+               n_block, total, impl):
+    """``_greedy_loop`` as one SPMD program over the lanes' devices (the
+    1-D ``mesh``). Each device holds its own shard, laid out alike on
+    every device: x (Np, d) at ``greedy_layout(n, n_block)``, mind, gidx
+    and ``statics`` (Np,); rows that hold no pool row carry gidx
+    ``_NO_ROW`` and never win. Per pick each device folds the last winner
+    into its shard with the fused round and takes its local argmax; one
+    all-gather (``alaas.select.merge``) hands every device each proposal's
+    score, global index and row, and every device picks the same winner by
+    ``_merge_on_device``'s rule, whose row is the next fold's center.
+    ``total`` (static) is the pool's row count that ``weight_for_slot``
+    draws over. Returns the replicated ``(sel, scores)``, buffers of
+    ``mesh.size * Np`` slots, as ``_greedy_loop``'s."""
+    from repro.kernels.pairwise import ops
+    axis = mesh.axis_names[0]
+    f32, i32 = jnp.float32, jnp.int32
+    bitcast = jax.lax.bitcast_convert_type
+
+    def local(x, mind, gidx, statics, weight_for_slot, bounds):
+        d = x.shape[1]
+        me = jax.lax.axis_index(axis)
+        real = gidx != _NO_ROW
+        rows = jnp.where(real, gidx, 0)    # in range for a weights gather
+        start, stop = bounds[0], bounds[1]
+
+        def weights(slot):
+            if statics is not None:
+                return statics
+            if weight_for_slot is None:
+                return None
+            return weight_for_slot(slot, (rows,), total)[0]
+
+        def merge(li, lv):
+            # one collective per pick: the proposals travel as int32 bits
+            with jax.named_scope("alaas.select.merge"):
+                bits = jnp.concatenate([bitcast(x[li], i32),
+                                        jnp.stack([bitcast(lv, i32),
+                                                   gidx[li]])])
+                props = jax.lax.all_gather(bits, axis)      # (L, d + 2)
+                vals, gids = bitcast(props[:, d], f32), props[:, d + 1]
+                k = jnp.argmin(jnp.where(vals == jnp.max(vals), gids,
+                                         _NO_ROW))
+                return k, gids[k], vals[k], bitcast(props[k, :d], f32)
+
+        m = jnp.where(real, mind.astype(f32), -1.0)
+        sc = ops.masked_weighted_score(m, weights(start))
+        li = jnp.argmax(sc).astype(i32)
+        k, g, v, center = merge(li, sc[li])
+        size = x.shape[0] * mesh.size
+        sel = jnp.zeros((size,), i32).at[start].set(g)
+        scores = jnp.zeros((size,), f32).at[start].set(v)
+
+        def body(slot, carry):
+            m, sel, scores, k, li, center = carry
+            w = weights(slot)
+            # the winner's own device masks it (its proposal was ``li``)
+            m, li, lv = ops.greedy_round_padded(
+                x, m, center[None, :], jnp.where(k == me, li, -1)[None],
+                None if w is None else w[None, :], n=n, n_block=n_block,
+                impl=impl)
+            li = li.astype(i32)
+            k, g, v, center = merge(li, lv)
+            return m, sel.at[slot].set(g), scores.at[slot].set(v), k, li, \
+                center
+
+        _, sel, scores, _, _, _ = jax.lax.fori_loop(
+            start + 1, stop, body, (m[None, :], sel, scores, k, li, center))
+        return sel, scores
+
+    lane = P(axis)
+    return shard_map(local, mesh=mesh,
+                     in_specs=(lane, lane, lane, lane, P(), P()),
+                     out_specs=(P(), P()))(
+        x, mind, gidx, statics, weight_for_slot, bounds)
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "fill"))
+def _pad_rows(a, rows, fill):
+    return jnp.pad(a, [(0, rows - a.shape[0])] + [(0, 0)] * (a.ndim - 1),
+                   constant_values=fill)
+
+
+def _lane_array(parts, rows: int, fill, dtype, lanes, sharding):
+    """The shards' ``parts`` as one array sharded over the lanes: each
+    padded to ``rows`` rows of ``fill`` on its lane's device. A host part
+    is padded on the host and uploaded, so no program depends on its
+    length; a device part is moved to its lane and padded there."""
+    out = []
+    for p, dev in zip(parts, lanes):
+        if isinstance(p, jax.Array):
+            out.append(_pad_rows(jax.device_put(p, dev).astype(dtype),
+                                 rows, fill))
+            continue
+        p = np.asarray(p, dtype)
+        buf = np.empty((rows,) + p.shape[1:], dtype)
+        buf[:len(p)] = p
+        buf[len(p):] = fill
+        out.append(telemetry.h2d(buf, device=dev))
+    return jax.make_array_from_single_device_arrays(
+        (rows * len(out),) + out[0].shape[1:], sharding, out)
+
+
+def _place_on_lanes(shards, emb_list, mind_list, weights_list,
+                    weight_for_slot, start, stop, impl, lanes):
+    """Upload the operands of ``_lane_loop`` over shards pinned one per
+    device, and return the call. Rows are padded to the largest shard's
+    pool (``pool_feats``; its view where there is none), which labels do
+    not change: the program is one per pool, whatever the unlabeled
+    split."""
+    from repro.kernels.pairwise import ops
+    from repro.kernels.pairwise.kernel import greedy_layout
+    mesh = Mesh(np.asarray(lanes, dtype=object), ("lanes",))
+    lane, whole = NamedSharding(mesh, P("lanes")), NamedSharding(mesh, P())
+    n = max(len(s.pool_feats) if s.pool_feats is not None else s.n
+            for s in shards)
+    d = int(np.shape(emb_list[0])[1])
+    n_block = ops.autotuned_blocks(n, d, jnp.float32).n_block
+    rows = greedy_layout(n, n_block)[1]
+    f32 = np.float32
+    x = _lane_array(emb_list, rows, 0.0, f32, lanes, lane)
+    mind = _lane_array([np.zeros((0,), f32) if m is None else m
+                        for m in mind_list], rows, -1.0, f32, lanes, lane)
+    gidx = _lane_array([s.gidx for s in shards], rows, _NO_ROW, np.int32,
+                       lanes, lane)
+    statics = (None if weights_list is None else
+               _lane_array(weights_list, rows, 0.0, f32, lanes, lane))
+    bounds = telemetry.h2d(np.asarray([start, stop], np.int32),
+                           device=whole)
+    return functools.partial(
+        _lane_loop, x, mind, gidx, statics,
+        jax.tree.map(lambda a: jax.device_put(a, whole), weight_for_slot),
+        bounds, mesh=mesh, n=n, n_block=n_block,
+        total=(None if weight_for_slot is None else replica_total(shards)),
+        impl=impl)
+
+
+def _place_on_one_device(shards, emb_list, mind_list, weights_list,
+                         weight_for_slot, start, stop, impl):
+    """Upload the operands of ``_greedy_loop`` to one device (the first
+    device array's, else the first shard's lane, else the default one),
+    and return the call."""
+    from repro.kernels.pairwise import ops
+    first = next((e for e in emb_list if isinstance(e, jax.Array)), None)
+    dev = (next(iter(first.devices())) if first is not None
+           else shards[0].device)
+
+    def put(x, dtype=None):
+        if isinstance(x, jax.Array):
+            return x if dev is None else jax.device_put(x, dev)
+        return telemetry.h2d(x, dtype, device=dev)
+
+    embs = tuple(put(e, jnp.float32) for e in emb_list)
+    minds = tuple(put(np.zeros((0,), np.float32) if m is None else m)
+                  for m in mind_list)
+    gidxs = tuple(put(np.asarray(s.gidx, np.int32)) for s in shards)
+    bounds = put(np.asarray([start, stop], np.int32))
+    blocks = tuple(ops.autotuned_blocks(*e.shape, e.dtype).n_block
+                   if e.shape[0] else 0 for e in embs)
+    return functools.partial(
+        _greedy_loop, embs, minds, gidxs, jax.tree.map(put, weight_for_slot),
+        bounds,
+        None if weights_list is None else tuple(map(put, weights_list)),
+        blocks=blocks, impl=impl)
+
+
 def replica_greedy_select(shards: Sequence[ShardView],
-                          emb_list: Sequence[jax.Array], budget: int, *,
-                          mind_list: Sequence[Optional[jax.Array]],
+                          emb_list: Sequence[Any], budget: int, *,
+                          mind_list: Sequence[Optional[Any]],
                           sel: np.ndarray, start: int,
+                          weights_list: Optional[Sequence[Any]] = None,
                           weight_for_slot=None, impl: str = "auto",
                           capture: Optional[list] = None) -> np.ndarray:
     """Local-propose / global-dedup greedy rounds over replica shards —
@@ -620,49 +800,55 @@ def replica_greedy_select(shards: Sequence[ShardView],
     single-pool greedy loop: the per-row floats are slice-invariant and
     both tie-break layers reduce to the lowest global index.
 
-    Slots ``start .. budget`` run as one device program (``_greedy_loop``)
-    whose compile depends on the shards' shapes only, not on the budget;
-    the host reads the selection back once, at the end.
-    ``weight_for_slot`` is None (unweighted) or a ``jax.tree_util.Partial``
-    traced as ``weight_for_slot(slot, gidxs)`` into the shards' weight
-    vectors for ``slot``'s pick (``static_weights``; BADGE's Gumbel draws).
+    Slots ``start .. budget`` run as one device program whose compile
+    depends on the shards' shapes only, not on the budget; the host reads
+    the selection back once, at the end. Shards pinned one per device
+    (``lane_devices``) run it as one SPMD program across those devices
+    (``_lane_loop``): each device holds and folds its own shard, and the
+    proposals meet in one all-gather per pick. Otherwise every shard's
+    arrays are put on one device (``_greedy_loop``).
+
+    ``emb_list``/``mind_list`` hold each shard's pool rows and min-dists,
+    host or device arrays (a host array is uploaded where it runs).
+    ``weights_list`` holds static per-shard weights (weighted k-center,
+    margin density); ``weight_for_slot`` is instead a
+    ``jax.tree_util.Partial`` traced as ``weight_for_slot(slot, gidxs,
+    total)`` into the weight vectors of the rows at global positions
+    ``gidxs`` for ``slot``'s pick, out of a ``total``-row pool (BADGE's
+    Gumbel draws).
 
     ``capture`` (optional list) records the merged winner's score per slot
     in slot order — the standing-query replay engine (service layer) stores
     them so a later emit over a grown pool can prove "no new row beats any
     recorded winner" by streaming only the delta rows.
 
-    Counts its picks in ``select.picks``, its read-back in
-    ``select.d2h_syncs`` and its uploads (row indices, bounds) in
-    ``h2d_bytes``. The shards' arrays are put on the first one's device.
+    The span ``select.greedy`` covers the loop, from its dispatch to its
+    selection on the host; the uploads come before it. Counts its picks
+    in ``select.picks``, its read-back in ``select.d2h_syncs``, the
+    devices its program spans in ``select.loop_devices`` and its uploads
+    in ``h2d_bytes``.
     """
     from repro.kernels.pairwise import ops
     stop = min(budget, replica_total(shards))
     if stop <= start:
         return sel
-    dev = next(iter(emb_list[0].devices()))
-
-    def put(x):
-        return jax.device_put(x, dev)
-
-    embs = tuple(put(e) for e in emb_list)
-    minds = tuple(put(jnp.zeros((0,), jnp.float32) if m is None else m)
-                  for m in mind_list)
-    gidxs = tuple(put(telemetry.h2d(s.gidx, jnp.int32)) for s in shards)
-    bounds = put(telemetry.h2d(np.asarray([start, stop], np.int32)))
-    blocks = tuple(ops.autotuned_blocks(*e.shape, e.dtype).n_block
-                   if e.shape[0] else 0 for e in embs)
-    got, scores = jax.device_get(_greedy_loop(
-        embs, minds, gidxs, jax.tree.map(put, weight_for_slot), bounds,
-        blocks=blocks, impl=impl))
-    for e in embs:
-        if e.shape[0]:
+    lanes = lane_devices(shards)
+    args = (shards, emb_list, mind_list, weights_list, weight_for_slot,
+            start, stop, impl)
+    loop = (_place_on_one_device(*args) if lanes is None
+            else _place_on_lanes(*args, lanes))
+    with telemetry.span("select.greedy"):
+        got, scores = jax.device_get(loop())
+    for e in emb_list:
+        if np.shape(e)[0]:
             ops.record_greedy_rounds(e, stop - start - 1)
     sel[start:stop] = got[start:stop]
     if capture is not None:
         capture.extend(float(v) for v in scores[start:stop])
     telemetry.count("select.picks", stop - start)
     telemetry.count("select.d2h_syncs", 1)
+    telemetry.count("select.loop_devices", 1 if lanes is None
+                    else len(lanes))
     return sel
 
 
@@ -687,19 +873,13 @@ class KCenterState:
 
     def view_minds(self, shards) -> list:
         """Per-shard min-dists gathered down to the query's (unlabeled)
-        view rows, as jnp arrays ready for the greedy loop. Requires
-        ``ShardView.pool_rows``. Row gathers reproduce the exact floats a
-        from-scratch ``warm_start_min_dist`` over the view would compute:
-        per-(row, center) distances are slice-invariant (module contract)
-        and the min fold is exact."""
-        out = []
-        for i, s in enumerate(shards):
-            if s.n == 0:
-                out.append(None)
-                continue
-            out.append(telemetry.h2d(
-                self.minds[i][np.asarray(s.pool_rows)]))
-        return out
+        view rows, as host arrays that the greedy loop uploads where it
+        runs. Requires ``ShardView.pool_rows``. Row gathers reproduce the
+        exact floats a from-scratch ``warm_start_min_dist`` over the view
+        would compute: per-(row, center) distances are slice-invariant
+        (module contract) and the min fold is exact."""
+        return [self.minds[i][np.asarray(s.pool_rows)] if s.n else None
+                for i, s in enumerate(shards)]
 
     def pool_mind(self, i: int) -> np.ndarray:
         return self.minds[i]

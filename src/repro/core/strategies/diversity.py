@@ -196,30 +196,35 @@ def sharded_k_center(rng, budget: int, shards, *, init_centers=None,
             slack=prefilter.slack, executor=executor, impl=impl,
             state=state if warm else None)
     N = selection.replica_total(shards)
-    emb_list = [telemetry.h2d(s.feats, jnp.float32) for s in shards]
     sel = np.zeros((budget,), np.int64)
     capture = None
-    if warm:
-        if state is not None:
-            mind = state.view_minds(shards)
-            capture = state.capture
-        else:
+    if warm and state is not None:
+        # host rows and min-dists: the loop uploads them where it runs
+        emb_list = [s.feats for s in shards]
+        mind = state.view_minds(shards)
+        capture = state.capture
+        start = 0
+    else:
+        # each shard's rows on its lane's device when the loop runs
+        # across devices (the default device otherwise)
+        lanes = selection.lane_devices(shards) or [None] * len(shards)
+        emb_list = [telemetry.h2d(s.feats, jnp.float32, device=dev)
+                    for s, dev in zip(shards, lanes)]
+        if warm:
             init = telemetry.h2d(init_centers, jnp.float32)
             mind = [ops.warm_start_min_dist(emb_list[i], init, impl=impl)
                     if s.n else None for i, s in enumerate(shards)]
-        start = 0
-    else:
-        # the random seed IS the first returned center, as in the single
-        # path (same rng call, same N -> same draw)
-        first = int(jax.random.randint(rng, (), 0, N))
-        mind = selection.replica_seed_min_dist(shards, emb_list, first)
-        sel[0] = first
-        start = 1
+            start = 0
+        else:
+            # the random seed IS the first returned center, as in the
+            # single path (same rng call, same N -> same draw)
+            first = int(jax.random.randint(rng, (), 0, N))
+            mind = selection.replica_seed_min_dist(shards, emb_list, first)
+            sel[0] = first
+            start = 1
     return selection.replica_greedy_select(
         shards, emb_list, budget, mind_list=mind, sel=sel, start=start,
-        weight_for_slot=(None if weights_list is None
-                         else selection.static_weights(weights_list)),
-        impl=impl, capture=capture)
+        weights_list=weights_list, impl=impl, capture=capture)
 
 
 def _kcg_sharded(rng, budget, shards, *, labeled_embeddings=None,

@@ -130,11 +130,10 @@ def sharded_kmeans_pp(rng, x_list, shards, k: int, impl: str = "auto"):
         impl=impl)
 
 
-def _gumbel_weights(keys, slot, gidxs):
-    """Slot ``slot``'s D² draw, ``exp(gumbel(keys[slot], (N,)))``, sliced
-    to each shard's rows."""
-    n = sum(g.shape[0] for g in gidxs)
-    w = jnp.exp(jax.random.gumbel(keys[slot], (n,), jnp.float32))
+def _gumbel_weights(keys, slot, gidxs, total):
+    """Slot ``slot``'s D² draw, ``exp(gumbel(keys[slot], (total,)))``,
+    gathered at the global rows ``gidxs``."""
+    w = jnp.exp(jax.random.gumbel(keys[slot], (total,), jnp.float32))
     return tuple(w[g] for g in gidxs)
 
 

@@ -160,6 +160,11 @@ class ShardWorkerPool:
         self.tasks = 0             # supervised tasks completed
         self.deaths: List[str] = []   # human-readable death log
 
+    @property
+    def devices(self) -> List[Any]:
+        """Each lane's pinned device (None on a single-device host)."""
+        return list(self._devices)
+
     # -- executor protocol -------------------------------------------------
     def map(self, fn: Callable, items) -> list:
         return self._map(fn, items, phase="shard", on_death=None,

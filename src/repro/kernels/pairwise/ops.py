@@ -185,7 +185,9 @@ def greedy_round_padded(xp, mind, centers, sel_idx, weights, *, n: int,
     n_block)`` — xp (Np, d), mind and weights (1, Np) rows — for loops
     that trace many rounds over one pool. Returns ``(new_mind (1, Np),
     next_idx, next_score)``; rows ``n:`` never win and their min-dist is
-    not read. Call it inside a trace: it is not jitted and records no op
+    not read. Shards of different lengths share one shape by carrying -1
+    min-dists past their own length (selected rows never win either).
+    Call it inside a trace: it is not jitted and records no op
     accounting."""
     with jax.named_scope("alaas._greedy_round"):
         if impl == "auto":

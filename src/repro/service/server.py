@@ -897,6 +897,8 @@ class ALSession:
                 rows[si].append(li)
                 gpos[si].append(g)
             pf_cfg = self._prefilter_cfg
+            rt = self.server.shard_runtime()
+            lanes = rt.devices if rt is not None else [None] * self.replicas
             shards = []
             for si in range(self.replicas):
                 r = np.asarray(rows[si], np.int64)
@@ -916,7 +918,8 @@ class ALSession:
                     # shard-local pool position (cheap views, always set)
                     pool_rows=r,
                     pool_feats=feats_l[si],
-                    probs_epoch=epochs[si]))
+                    probs_epoch=epochs[si],
+                    device=lanes[si]))
             labeled_emb = None
             lab: List[Tuple[int, int]] = []
             if self._labeled_keys:

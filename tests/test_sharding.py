@@ -387,3 +387,196 @@ def test_kernel_scores_with_empty_shard(strategy, uncertainty_interpret):
            for r, s in srvs.items()}
     assert len(res[1]["keys"]) == 4
     assert res[3]["keys"] == res[1]["keys"]
+
+
+# ------------------------------------- the pick loop across four devices --
+FOUR_DEVICE_PROGRAM = r'''
+import json
+import sys
+import numpy as np
+import jax
+import jax.numpy as jnp
+sys.path.insert(0, "tests")
+from test_sharding import _flat_family, _make_shards, _sharded_family
+from repro.common import telemetry
+from repro.core import selection
+from repro.core.strategies.diversity import sharded_k_center
+from repro.data.synthetic import image_pool
+from repro.service.backends import MLPBackend
+from repro.service.client import ALClient
+from repro.service.config import ALServiceConfig
+from repro.service.server import ALServer
+
+devs = jax.devices()
+assert len(devs) == 4
+rng = np.random.default_rng(7)
+N, d = 61, 16
+FEATS = rng.standard_normal((N, d)).astype(np.float32)
+LABELED = rng.standard_normal((7, d)).astype(np.float32)
+KEYS = [f"pool-{i}" for i in range(N)]
+out = {}
+
+placed = []
+real_loop = selection._lane_loop
+
+
+def recording(x, mind, gidx, statics, wfs, bounds, **kw):
+    res = real_loop(x, mind, gidx, statics, wfs, bounds, **kw)
+    placed.append({
+        "operands": [[str(s.device) for s in a.addressable_shards]
+                     for a in (x, mind, gidx)],
+        "rows": [s.data.shape[0] for s in x.addressable_shards],
+        "outputs": [sorted(str(dv) for dv in r.sharding.device_set)
+                    for r in res]})
+    return res
+
+
+selection._lane_loop = recording
+
+
+def shards_of(feats, keys, pinned):
+    shards = _make_shards(feats, feats, keys, 4)
+    for s, dev in zip(shards, devs):
+        s.device = dev if pinned else None
+    return shards
+
+
+def captured(family, key, budget, shards, w):
+    cap = []
+    real = selection.replica_greedy_select
+
+    def capturing(*a, **kw):
+        kw["capture"] = cap
+        return real(*a, **kw)
+
+    selection.replica_greedy_select = capturing
+    try:
+        got = _sharded_family(family, key, budget, shards, LABELED, w)
+    finally:
+        selection.replica_greedy_select = real
+    return np.asarray(got).tolist(), cap
+
+
+for family in ("seeded", "warm", "static", "gumbel"):
+    for pool, n, budget in (("ragged", N, 9), ("empty_shard", 5, 4)):
+        key = jax.random.PRNGKey(13)
+        sel, scores, w = _flat_family(family, key, budget, FEATS[:n],
+                                      LABELED)
+        res = {"flat": [np.asarray(sel).tolist(), scores], "empty": None}
+        for name, pinned in (("one_device", False), ("four_devices", True)):
+            shards = shards_of(FEATS[:n], KEYS[:n], pinned)
+            res["empty"] = any(s.n == 0 for s in shards)
+            del placed[:]
+            res[name] = captured(family, key, budget, shards, w)
+            res[name + "_programs"] = len(placed)
+        out[f"{family}-{pool}"] = res
+
+# placement and compiles of one warm query over pinned shards
+shards = shards_of(FEATS, KEYS, True)
+del placed[:]
+
+
+def warm(budget):
+    return sharded_k_center(jax.random.PRNGKey(0), budget, shards,
+                            init_centers=jnp.asarray(LABELED))
+
+
+warm(1)
+out["placement"] = placed[0]
+out["shard_rows"] = [s.n for s in shards]
+c0 = telemetry.snapshot()["counters"].get("compile.count", 0)
+warm(40)
+out["compiles_budget_40"] = (
+    telemetry.snapshot()["counters"].get("compile.count", 0) - c0)
+
+# replicas 4 over the client, lanes pinned one per device, vs replicas 1
+X, Y = image_pool(80, seed=5)
+served = {}
+loop_devices = {}
+for r in (1, 4):
+    srv = ALServer(ALServiceConfig(batch_size=16, replicas=r),
+                   backend=MLPBackend(in_dim=192, feat_dim=32))
+    cli = ALClient(local=srv)
+    keys = cli.push_data(list(X))
+    cli.label(keys[:13], Y[:13].tolist())
+    cli.train_eval()
+    c0 = telemetry.snapshot()["counters"].get("select.loop_devices", 0)
+    served[r] = {s: cli.query(7, s, rng_seed=3)["keys"]
+                 for s in ("coreset", "kcg", "badge", "weighted_kcenter")}
+    loop_devices[r] = (
+        telemetry.snapshot()["counters"].get("select.loop_devices", 0) - c0)
+    cli.close()
+out["served_equal"] = {s: served[4][s] == served[1][s] for s in served[1]}
+out["served_loop_devices"] = loop_devices
+print("RESULT " + json.dumps(out))
+'''
+
+
+@pytest.fixture(scope="module")
+def four_devices():
+    """One run of ``FOUR_DEVICE_PROGRAM`` on four forced host devices (a
+    subprocess: the device count is fixed when JAX starts)."""
+    import json
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    prog = ('import os\nos.environ["XLA_FLAGS"] = '
+            '"--xla_force_host_platform_device_count=4"\n'
+            + FOUR_DEVICE_PROGRAM)
+    r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                       text=True, cwd=root,
+                       env=dict(os.environ, PYTHONPATH="src",
+                                JAX_PLATFORMS="cpu"))
+    assert r.returncode == 0, (r.stdout[-1000:], r.stderr[-3000:])
+    line = next(ln for ln in r.stdout.splitlines()
+                if ln.startswith("RESULT "))
+    return json.loads(line[len("RESULT "):])
+
+
+@pytest.mark.parametrize("pool", ["ragged", "empty_shard"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_four_device_loop_matches_flat_path(family, pool, four_devices):
+    """Shards pinned one per device run the loop as one program across the
+    four devices; its selections and captured scores equal the flat path's
+    and the single-device loop's bitwise, weights absent, static or per
+    slot, on ragged shards and with an empty one."""
+    res = four_devices[f"{family}-{pool}"]
+    assert res["four_devices_programs"] == 1
+    assert res["one_device_programs"] == 0
+    if pool == "empty_shard":
+        assert res["empty"]
+    sel, scores = res["flat"]
+    for name in ("one_device", "four_devices"):
+        got, cap = res[name]
+        assert got == sel, (name, got, sel)
+        assert np.asarray(cap, np.float32).tobytes() == \
+            np.asarray(scores, np.float32).tobytes(), name
+
+
+def test_four_device_loop_operands_on_lanes(four_devices):
+    """Each shard's pool rows, min-dists and row indices sit on its own
+    device, padded to one layout; the selection comes back replicated."""
+    p = four_devices["placement"]
+    devs = [f"TFRT_CPU_{i}" for i in range(4)]
+    for shards in p["operands"]:
+        assert sorted(shards) == devs
+        assert shards == p["operands"][0]
+    assert len(set(p["rows"])) == 1
+    assert p["rows"][0] >= max(four_devices["shard_rows"])
+    assert p["outputs"] == [devs, devs]
+
+
+def test_four_device_loop_compiles_once_per_pool_shape(four_devices):
+    """A budget-40 query after a budget-1 one over the same pinned shards
+    compiles nothing."""
+    assert four_devices["compiles_budget_40"] == 0
+
+
+def test_four_device_replicas_4_served_like_replicas_1(four_devices):
+    """replicas=4 with its lanes pinned to four devices selects the same
+    keys over ALClient as replicas=1, and each of its k-center-lineage
+    queries spans four devices."""
+    assert all(four_devices["served_equal"].values()), \
+        four_devices["served_equal"]
+    assert four_devices["served_loop_devices"]["4"] == 4 * 4

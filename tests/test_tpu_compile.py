@@ -13,6 +13,8 @@ pool dtypes, so no pick the model can make is refused on the chip.
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -146,3 +148,35 @@ def test_autotune_candidates_compile(one_chip, variant, d, dtype):
                      ((nn,), jnp.int32), ((nn,), jnp.int32),
                      ((SWEEP_N, d), dtype), ((SWEEP_N,), jnp.float32),
                      ((rb, d), jnp.float32))
+
+
+def test_four_chip_pick_loop_compiles(topo):
+    """The pick loop across chips as one SPMD program for the described
+    v5e:2x2: four shards of 12,500 x 512, one per chip, unweighted, with
+    the budget a traced bound. Per pick the kernel runs on every chip and
+    the proposals meet in one all-gather inside the loop."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.core.selection import _lane_loop
+    from repro.kernels.pairwise.kernel import greedy_layout
+    rows = 12_500
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("lanes",))
+    lane, whole = NamedSharding(mesh, P("lanes")), NamedSharding(mesh, P())
+    n_block = autotune.autotune_blocks(rows, D, jnp.float32).n_block
+    padded = greedy_layout(rows, n_block)[1]
+    sds = jax.ShapeDtypeStruct
+    compiled = _lane_loop.lower(
+        sds((4 * padded, D), jnp.float32, sharding=lane),
+        sds((4 * padded,), jnp.float32, sharding=lane),
+        sds((4 * padded,), jnp.int32, sharding=lane), None, None,
+        sds((2,), jnp.int32, sharding=whole), mesh=mesh, n=rows,
+        n_block=n_block, total=None, impl="pallas").compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "while" in text
+    # one collective for the first pick, one in the loop's body (the
+    # compiler may lower the all-gather to an all-reduce)
+    collective = re.compile(r"\s(all-gather|all-reduce)(-start)?\(")
+    assert sum(bool(collective.search(line))
+               for line in text.splitlines()) == 2
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2 << 30
