@@ -15,6 +15,7 @@ contract to the sequence axis: its blockwise-chunked forward
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -57,26 +58,11 @@ class FeatureBackend:
     def fit_head(self, feats: np.ndarray, labels: np.ndarray,
                  steps: int = 200, lr: float = 0.5,
                  head: Optional[HeadState] = None) -> HeadState:
-        x = jnp.asarray(feats, jnp.float32)
-        y = jnp.asarray(labels, jnp.int32)
         if head is None:
             head = self.init_head()
-
-        def loss_fn(p):
-            logits = x @ p["w"] + p["b"]
-            lp = jax.nn.log_softmax(logits)
-            nll = -jnp.mean(jnp.take_along_axis(lp, y[:, None], 1))
-            return nll + 1e-4 * jnp.sum(p["w"] ** 2)
-
-        @jax.jit
-        def fit_head_step(p, _):
-            with jax.named_scope("alaas.fit_head_step"):
-                g = jax.grad(loss_fn)(p)
-                return jax.tree.map(lambda a, b: a - lr * b, p, g), None
-
-        p = {"w": head.w, "b": head.b}
-        p, _ = jax.lax.scan(fit_head_step, p, None, length=steps)
-        return HeadState(w=p["w"], b=p["b"])
+        x, y, mask = _padded(feats, labels)
+        w, b = _fit_head(head.w, head.b, x, y, mask, lr, steps=steps)
+        return HeadState(w=w, b=b)
 
     def probs(self, feats: np.ndarray, head: HeadState) -> np.ndarray:
         return np.asarray(_head_probs(jnp.asarray(feats, jnp.float32),
@@ -84,8 +70,56 @@ class FeatureBackend:
 
     def evaluate(self, feats: np.ndarray, labels: np.ndarray,
                  head: HeadState) -> float:
-        p = self.probs(feats, head)
-        return float(np.mean(p.argmax(-1) == np.asarray(labels)))
+        x, y, mask = _padded(feats, labels)
+        return float(_head_correct(x, y, mask, head.w, head.b)) / len(feats)
+
+
+# The labeled rows are padded to a power-of-two bucket (at least
+# MIN_BUCKET), so one compiled fit and one evaluation serve every labeled
+# count in a bucket; the features are arguments, never constants baked
+# into a program per call.
+MIN_BUCKET = 256
+
+
+def _padded(feats, labels):
+    """Rows padded with zero features, label 0 and mask 0 to their
+    bucket: (x, y, mask) as float32, int32, float32."""
+    n = len(feats)
+    rows = max(MIN_BUCKET, 1 << (n - 1).bit_length())
+    x = np.zeros((rows,) + np.shape(feats)[1:], np.float32)
+    x[:n] = feats
+    y = np.zeros((rows,), np.int32)
+    y[:n] = labels
+    mask = np.zeros((rows,), np.float32)
+    mask[:n] = 1.0
+    return x, y, mask
+
+
+@functools.partial(jax.jit, static_argnames=("steps",))
+def _fit_head(w, b, x, y, mask, lr, *, steps):
+    """``steps`` of gradient descent on the softmax head: the mean
+    negative log-likelihood over the rows with mask 1, plus weight decay.
+    A padded row (zero features, mask 0) adds nothing to the gradient."""
+    def loss_fn(p):
+        lp = jax.nn.log_softmax(x @ p["w"] + p["b"])
+        nll = -jnp.sum(mask * jnp.take_along_axis(lp, y[:, None], 1)[:, 0])
+        return nll / jnp.sum(mask) + 1e-4 * jnp.sum(p["w"] ** 2)
+
+    def fit_head_step(p, _):
+        with jax.named_scope("alaas.fit_head_step"):
+            g = jax.grad(loss_fn)(p)
+            return jax.tree.map(lambda a, b: a - lr * b, p, g), None
+
+    p, _ = jax.lax.scan(fit_head_step, {"w": w, "b": b}, None, length=steps)
+    return p["w"], p["b"]
+
+
+@jax.jit
+def _head_correct(x, y, mask, w, b):
+    """Rows with mask 1 whose logits' argmax is their label."""
+    with jax.named_scope("alaas.head_correct"):
+        hit = jnp.argmax(x @ w + b, axis=-1) == y
+        return jnp.sum(jnp.where(mask > 0, hit, False))
 
 
 @jax.jit

@@ -3,9 +3,11 @@ keys, clear input validation, and the cross-backend parity suite (chunk
 invariance, eviction-recompute bit-identity, deterministic head fits).
 """
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.common import telemetry
 from repro.data.synthetic import audio_pool, image_pool, text_pool
 from repro.service.backends import (MLPBackend, ResNetBackend,
                                     TransformerBackend, make_backend)
@@ -135,3 +137,77 @@ def test_backend_head_fit_deterministic(case):
     h2 = be.fit_head(feats, labels, head=be.init_head(key))
     assert np.array_equal(h1.w, h2.w) and np.array_equal(h1.b, h2.b)
     assert np.array_equal(be.probs(feats, h1), be.probs(feats, h2))
+
+
+# ------------------------------------------------------ bucketed head fit --
+def _reference_fit(feats, labels, head, steps=200, lr=0.5):
+    """The head fit on the rows as they are, with no padding: the mean
+    negative log-likelihood plus weight decay, one ``lax.scan``."""
+    x = jnp.asarray(feats, jnp.float32)
+    y = jnp.asarray(labels, jnp.int32)
+
+    def loss_fn(p):
+        lp = jax.nn.log_softmax(x @ p["w"] + p["b"])
+        nll = -jnp.mean(jnp.take_along_axis(lp, y[:, None], 1))
+        return nll + 1e-4 * jnp.sum(p["w"] ** 2)
+
+    def step(p, _):
+        g = jax.grad(loss_fn)(p)
+        return jax.tree.map(lambda a, b: a - lr * b, p, g), None
+
+    p, _ = jax.jit(lambda p: jax.lax.scan(step, p, None, length=steps))(
+        {"w": head.w, "b": head.b})
+    return p["w"], p["b"]
+
+
+_HEAD_BACKENDS = {
+    "mlp": lambda k=10: MLPBackend(in_dim=48, feat_dim=16, num_classes=k),
+    "synthetic_cnn": lambda k=10: make_backend("synthetic_cnn",
+                                               num_classes=k),
+}
+
+
+def _labeled(be, n, seed):
+    rng = np.random.default_rng(seed)
+    feats = np.tanh(rng.normal(size=(n, be.feat_dim))).astype(np.float32)
+    labels = rng.integers(0, be.num_classes, size=n)
+    return feats, labels
+
+
+@pytest.mark.parametrize("n", [1, 255, 256, 257, 1000, 4096])
+@pytest.mark.parametrize("case", sorted(_HEAD_BACKENDS))
+def test_bucketed_head_fit_matches_unpadded_reference(case, n):
+    """Padding the labeled rows to their bucket changes nothing: the
+    weights match an unpadded fit and the accuracy is the same."""
+    be = _HEAD_BACKENDS[case]()
+    feats, labels = _labeled(be, n, seed=n)
+    head = be.fit_head(feats, labels)
+    w, b = _reference_fit(feats, labels, be.init_head())
+    np.testing.assert_allclose(head.w, w, atol=1e-5)
+    np.testing.assert_allclose(head.b, b, atol=1e-5)
+    want = float(np.mean(
+        np.argmax(feats @ np.asarray(w) + np.asarray(b), -1) == labels))
+    assert be.evaluate(feats, labels, head) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("case", sorted(_HEAD_BACKENDS))
+def test_head_fit_compiles_once_per_bucket(case):
+    """Two labeled counts in one bucket run the same compiled fit and
+    evaluation: the second count compiles nothing."""
+    # 7 classes: a head shape no other test compiles, so the first count
+    # of the bucket compiles here whatever ran before
+    be = _HEAD_BACKENDS[case](7)
+    feats, labels = _labeled(be, 700, seed=1)
+
+    def compiles():
+        return telemetry.snapshot()["counters"].get("compile.count", 0)
+
+    with telemetry.span("test.head_fit"):
+        c0 = compiles()
+        be.evaluate(feats[:600], labels[:600],
+                    be.fit_head(feats[:600], labels[:600]))
+        c1 = compiles()
+        be.evaluate(feats, labels, be.fit_head(feats, labels))
+        c2 = compiles()
+    assert c1 > c0          # the first count of the bucket compiles
+    assert c2 == c1
